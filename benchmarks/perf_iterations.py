@@ -97,7 +97,8 @@ def main():
     ap.add_argument("--variant", required=True, choices=sorted(VARIANTS))
     args = ap.parse_args()
 
-    from repro.launch.dryrun import run_cell  # sets XLA_FLAGS first
+    from repro.launch.dryrun import run_cell, use_host_devices
+    use_host_devices()
     overrides, hypothesis = VARIANTS[args.variant]
     base = load_baseline(args.arch, args.shape)
     rec = run_cell(args.arch, args.shape, multi_pod=False, out_dir=PERF,

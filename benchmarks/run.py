@@ -7,6 +7,8 @@ import traceback
 
 def main() -> None:
     from benchmarks import kernels, paper_tables, roofline_report, serving_e2e
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sections = [
         ("paper_tables (RowClone + D-RaNGe reproduction)", paper_tables.main),
         ("kernels", kernels.main),

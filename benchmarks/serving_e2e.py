@@ -32,11 +32,10 @@ to the TPU framework), eight tables:
 6. Tensor-parallel sharded serving: mesh {1, 2, 4} × logit collective
    {psum, psum_compressed} → decode tokens/s, batch TTFT, dispatches
    per round (still ONE — the shard_map program spans all shards), and
-   the per-shard ``launches_by_owner`` breakdown.  mesh=1 runs
-   in-process; mesh>1 cells run in a subprocess with
-   ``--xla_force_host_platform_device_count`` and are recorded as
-   skipped on boxes under 4 cores (XLA host collectives spin-wait and
-   deadlock there).
+   the per-shard ``launches_by_owner`` breakdown.  Every cell runs in
+   this process on its own devices; a CPU run gets mesh>1 devices from
+   ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (XLA host
+   collectives spin-wait and deadlock on boxes under 4 cores).
 
 7. Open-system saturation sweep: Poisson arrivals at >= 3 rates through
    the async front door (``repro.serving.server.AsyncServer``) on a
@@ -76,15 +75,14 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
-import textwrap
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.models.params import init_params
@@ -563,42 +561,23 @@ def _mesh_row_local(world: int, compressed: bool, smoke: bool) -> dict:
 
 
 def _mesh_table(smoke: bool) -> dict:
-    """Table 6 sweep.  mesh=1 in-process; mesh>1 needs N host devices,
-    which only exist under ``--xla_force_host_platform_device_count``
-    set before jax imports — so those cells run in a subprocess that
-    imports this module and calls :func:`_mesh_row_local`."""
+    """Table 6 sweep, every cell in this process on ``jax.devices()``:
+    one process holds all of a host's chips, and a child process could
+    not reach them.  A CPU host has N devices only under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set before
+    the run; a mesh wider than the process's devices is recorded as
+    skipped, and a cell that fails fails the run."""
     rows: dict = {}
-    src = os.path.join(_ROOT, "src")
+    n_dev = jax.device_count()
     for world in (1, 2, 4):
         for compressed in (False, True):
             key = f"mesh{world}_" + ("psum_compressed" if compressed
                                      else "psum")
-            if world == 1:
-                rows[key] = _mesh_row_local(1, compressed, smoke)
-            elif (os.cpu_count() or 1) < 4:
-                rows[key] = {"skipped":
-                             "host-mesh collectives need >=4 cores"}
-            else:
-                prog = textwrap.dedent(f"""
-                    import os, sys, json
-                    os.environ["XLA_FLAGS"] = (
-                        "--xla_force_host_platform_device_count={world}")
-                    sys.path.insert(0, {os.path.dirname(os.path.abspath(__file__))!r})
-                    import serving_e2e
-                    row = serving_e2e._mesh_row_local(
-                        {world}, {compressed}, {smoke})
-                    print("ROW=" + json.dumps(row))
-                """)
-                env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
-                res = subprocess.run([sys.executable, "-c", prog], env=env,
-                                     capture_output=True, text=True,
-                                     timeout=900)
-                if res.returncode != 0:
-                    rows[key] = {"error": (res.stderr or res.stdout)[-500:]}
-                    continue
-                line = [ln for ln in res.stdout.splitlines()
-                        if ln.startswith("ROW=")][-1]
-                rows[key] = json.loads(line[len("ROW="):])
+            if world > n_dev:
+                rows[key] = {"skipped": f"needs {world} devices, this "
+                                        f"process has {n_dev}"}
+                continue
+            rows[key] = _mesh_row_local(world, compressed, smoke)
     return rows
 
 
@@ -714,8 +693,7 @@ def main(out=sys.stdout, smoke: bool = False):
                   f";dispatches_per_round={row['dispatches_per_round']:.1f}",
                   file=out)
         else:
-            note = row.get("skipped", row.get("error", ""))
-            print(f"sharded_{key},0,skipped={note}", file=out)
+            print(f"sharded_{key},0,skipped={row['skipped']}", file=out)
 
     # ---- table 7: open-loop Poisson sweep, goodput under SLO ----------- #
     orows = _open_loop_table(cfg, params, smoke=smoke)
@@ -811,4 +789,5 @@ def main(out=sys.stdout, smoke: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(smoke="--smoke" in sys.argv)

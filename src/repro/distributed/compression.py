@@ -27,16 +27,6 @@ import numpy as np
 BLOCK = 256  # elements per quantization block
 
 
-def _axis_size(axis_name) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions:
-    ``jax.lax.axis_size`` only exists in newer releases; on 0.4.x the
-    axis env frame holds the size directly."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
-
-
 def _quantize(x: jax.Array):
     """x: (..., n) fp32 -> (int8 codes, fp32 scales per block)."""
     n = x.shape[-1]
@@ -63,7 +53,7 @@ def _dequantize(codes: jax.Array, scale: jax.Array, n: int) -> jax.Array:
 def psum_compressed(x: jax.Array, axis_name: str) -> jax.Array:
     """Compressed mean-preserving sum over ``axis_name`` (callable inside
     shard_map).  x: any shape; flattened internally."""
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     shape = x.shape
     flat = x.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
@@ -88,12 +78,11 @@ def psum_compressed(x: jax.Array, axis_name: str) -> jax.Array:
 
 def compressed_grad_sync(grads: Any, mesh, axis_name: str = "data") -> Any:
     """Tree-wise compressed all-reduce (replicated-gradient DP mode)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def sync(g):
         fn = functools.partial(psum_compressed, axis_name=axis_name)
-        return shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                         check_rep=False)(g)
+        return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(g)
 
     return jax.tree.map(sync, grads)

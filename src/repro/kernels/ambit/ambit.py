@@ -32,12 +32,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import interpret_mode, page_spec, paged_view
+
 
 def _page_bitwise_batched_kernel(src_idx_ref, dst_idx_ref, src_view_ref,
                                  dst_view_ref, out_ref, *, op: str):
-    # Grid: (layers, n_ops, col_blocks).  The index_maps route the two
-    # input views to arena[l, src[i]] / arena[l, dst[i]] and the output
-    # block back onto arena[l, dst[i]]; the body is one VPU op.
+    # Grid: (layers, n_ops, parts).  The index_maps route the two input
+    # views to arena[l, src[i]] / arena[l, dst[i]] and the output block
+    # back onto arena[l, dst[i]]; the body is one VPU op.
     del src_idx_ref, dst_idx_ref
     if op == "and":
         out_ref[...] = src_view_ref[...] & dst_view_ref[...]
@@ -47,40 +49,31 @@ def _page_bitwise_batched_kernel(src_idx_ref, dst_idx_ref, src_view_ref,
 
 def page_bitwise_batched(arena: jax.Array, src_pages: jax.Array,
                          dst_pages: jax.Array, op: str, *,
-                         block_cols: int = 4096,
-                         interpret: bool = False) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """``arena[:, dst[i]] <- op(arena[:, src[i]], arena[:, dst[i]])`` for
     all i across every layer in ONE launch.
 
-    arena: (layers, num_pages, page_elems) integer dtype; src/dst_pages:
+    arena: (layers, num_pages, *page) integer dtype; src/dst_pages:
     (n,) int32.  The arena is passed as both operand views and aliased
     into the output, so only touched pages are rewritten.
     """
     if op not in ("and", "or"):
         raise ValueError(f"unknown ambit bitwise op {op!r}")
-    layers, num_pages, page_elems = arena.shape
+    shape = arena.shape
+    a = paged_view(arena)
     n = src_pages.shape[0]
-    bc = min(block_cols, page_elems)
-    grid = (layers, n, pl.cdiv(page_elems, bc))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bc),
-                         lambda l, i, j, src_idx, dst_idx: (l, src_idx[i], j)),
-            pl.BlockSpec((1, 1, bc),
-                         lambda l, i, j, src_idx, dst_idx: (l, dst_idx[i], j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bc),
-                               lambda l, i, j, src_idx, dst_idx: (l, dst_idx[i], j)),
-    )
-    return pl.pallas_call(
+    src_spec, parts = page_spec(a, lambda i, s, d: s[i])
+    dst_spec, _ = page_spec(a, lambda i, s, d: d[i])
+    out = pl.pallas_call(
         functools.partial(_page_bitwise_batched_kernel, op=op),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(a.shape[0], n, parts),
+            in_specs=[src_spec, dst_spec], out_specs=dst_spec),
+        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         input_output_aliases={3: 0},  # dst view (after 2 prefetch args) -> out
-        interpret=interpret,
-    )(src_pages.astype(jnp.int32), dst_pages.astype(jnp.int32), arena, arena)
+        interpret=interpret_mode(interpret),
+    )(src_pages.astype(jnp.int32), dst_pages.astype(jnp.int32), a, a)
+    return out.reshape(shape)
 
 
 def _page_not_batched_kernel(src_idx_ref, dst_idx_ref, arena_ref, out_ref):
@@ -89,72 +82,54 @@ def _page_not_batched_kernel(src_idx_ref, dst_idx_ref, arena_ref, out_ref):
 
 
 def page_not_batched(arena: jax.Array, src_pages: jax.Array,
-                     dst_pages: jax.Array, *, block_cols: int = 4096,
-                     interpret: bool = False) -> jax.Array:
+                     dst_pages: jax.Array, *,
+                     interpret: bool | None = None) -> jax.Array:
     """``arena[:, dst[i]] <- ~arena[:, src[i]]`` across all layers in one
     launch (the dual-contact-cell NOT on pages)."""
-    layers, num_pages, page_elems = arena.shape
+    shape = arena.shape
+    a = paged_view(arena)
     n = src_pages.shape[0]
-    bc = min(block_cols, page_elems)
-    grid = (layers, n, pl.cdiv(page_elems, bc))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bc),
-                         lambda l, i, j, src_idx, dst_idx: (l, src_idx[i], j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bc),
-                               lambda l, i, j, src_idx, dst_idx: (l, dst_idx[i], j)),
-    )
-    return pl.pallas_call(
+    src_spec, parts = page_spec(a, lambda i, s, d: s[i])
+    dst_spec, _ = page_spec(a, lambda i, s, d: d[i])
+    out = pl.pallas_call(
         _page_not_batched_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(a.shape[0], n, parts),
+            in_specs=[src_spec], out_specs=dst_spec),
+        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         input_output_aliases={2: 0},
-        interpret=interpret,
-    )(src_pages.astype(jnp.int32), dst_pages.astype(jnp.int32), arena)
+        interpret=interpret_mode(interpret),
+    )(src_pages.astype(jnp.int32), dst_pages.astype(jnp.int32), a)
+    return out.reshape(shape)
 
 
 def _page_zero_scan_kernel(page_idx_ref, arena_ref, out_ref):
-    # Grid: (n_pages, layers, col_blocks) — the page index is OUTERMOST so
-    # every revisit of a page's (1, 1) output flag is consecutive (the
-    # standard Pallas accumulation pattern).
+    # Grid: (n_pages, layers, parts) — the page index is OUTERMOST so
+    # every revisit of a page's flag is consecutive; the flags live in
+    # SMEM for the whole grid and are written back once at the end.
     del page_idx_ref
-    l = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when((l == 0) & (j == 0))
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    nz = jnp.any(arena_ref[...] != 0).astype(jnp.int32)
-    out_ref[0, 0] |= nz
+    i = pl.program_id(0)
+    first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
+    nz = jnp.max((arena_ref[...] != 0).astype(jnp.int32))
+    out_ref[i] = jnp.where(first, nz, jnp.maximum(out_ref[i], nz))
 
 
 def page_zero_scan(arena: jax.Array, pages: jax.Array, *,
-                   block_cols: int = 4096,
-                   interpret: bool = False) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Per-page nonzero flags: ``out[i] = any(arena[:, pages[i]] != 0)``.
 
-    arena: (layers, num_pages, page_elems) integer dtype; pages: (n,)
-    int32.  Returns (n, 1) int32 — 0 where the page is all-zero bits
-    across every layer.  Read-only (no aliasing)."""
-    layers, num_pages, page_elems = arena.shape
+    arena: (layers, num_pages, *page) integer dtype; pages: (n,) int32.
+    Returns (n,) int32 — 0 where the page is all-zero bits across every
+    layer.  Read-only (no aliasing)."""
+    a = paged_view(arena)
     n = pages.shape[0]
-    bc = min(block_cols, page_elems)
-    grid = (n, layers, pl.cdiv(page_elems, bc))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bc), lambda i, l, j, page_idx: (l, page_idx[i], j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, l, j, page_idx: (i, 0)),
-    )
+    spec, parts = page_spec(a, lambda i, p: p[i], layer_minor=True)
     return pl.pallas_call(
         _page_zero_scan_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
-        interpret=interpret,
-    )(pages.astype(jnp.int32), arena)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n, a.shape[0], parts),
+            in_specs=[spec],
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM)),
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        interpret=interpret_mode(interpret),
+    )(pages.astype(jnp.int32), a)

@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Ambit bitwise kernel family.
 
-``use_pallas`` selects the Pallas kernel (TPU target; interpret-mode on
-CPU) vs the pure-jnp reference, mirroring the RowClone wrappers.  Bitwise
+``use_pallas`` selects the Pallas kernel vs the pure-jnp reference,
+mirroring the RowClone wrappers (``interpret=None`` lets the backend
+decide how the kernel runs).  Bitwise
 ops are defined on *bit patterns*: float arenas are bitcast to a matching
 unsigned view, operated on, and bitcast back, so both paths are bit-exact
 for any storage dtype.
@@ -15,8 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from . import ambit, ref
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 _UINT_FOR_ITEMSIZE = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
 
@@ -34,7 +33,7 @@ def _as_bits(arena: jax.Array):
 def pim_page_bitwise_batched(arena: jax.Array, src_pages: jax.Array,
                              dst_pages: jax.Array, *, op: str,
                              use_pallas: bool = False,
-                             interpret: bool = not _ON_TPU) -> jax.Array:
+                             interpret: bool | None = None) -> jax.Array:
     """``arena[:, dst[i]] <- op(arena[:, src[i]], arena[:, dst[i]])``
     (op in {"and", "or"}) or ``<- ~arena[:, src[i]]`` (op == "not"),
     across all layers in one fused launch.  arena: (layers, pages, ...)."""
@@ -46,16 +45,12 @@ def pim_page_bitwise_batched(arena: jax.Array, src_pages: jax.Array,
             out = ref.page_not_batched(bits, src_pages, dst_pages)
         else:
             out = ref.page_bitwise_batched(bits, src_pages, dst_pages, op)
+    elif op == "not":
+        out = ambit.page_not_batched(bits, src_pages, dst_pages,
+                                     interpret=interpret)
     else:
-        L, P = bits.shape[:2]
-        flat = bits.reshape(L, P, -1)
-        if op == "not":
-            out = ambit.page_not_batched(flat, src_pages, dst_pages,
+        out = ambit.page_bitwise_batched(bits, src_pages, dst_pages, op,
                                          interpret=interpret)
-        else:
-            out = ambit.page_bitwise_batched(flat, src_pages, dst_pages, op,
-                                             interpret=interpret)
-        out = out.reshape(bits.shape)
     if orig_dtype is not None:
         out = jax.lax.bitcast_convert_type(out, orig_dtype)
     return out
@@ -64,7 +59,7 @@ def pim_page_bitwise_batched(arena: jax.Array, src_pages: jax.Array,
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def pim_page_zero_scan(arena: jax.Array, pages: jax.Array, *,
                        use_pallas: bool = False,
-                       interpret: bool = not _ON_TPU) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """Per-page zero-compare: returns bool (n,), True where
     ``arena[:, pages[i]]`` is all-zero bits across every layer.
 
@@ -75,7 +70,4 @@ def pim_page_zero_scan(arena: jax.Array, pages: jax.Array, *,
     bits, _ = _as_bits(arena)
     if not use_pallas:
         return ref.page_zero_scan(bits, pages)
-    L, P = bits.shape[:2]
-    flags = ambit.page_zero_scan(bits.reshape(L, P, -1), pages,
-                                 interpret=interpret)
-    return flags[:, 0] == 0
+    return ambit.page_zero_scan(bits, pages, interpret=interpret) == 0

@@ -20,11 +20,15 @@ same arithmetic in plain jnp — oracles match bit-for-bit.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import interpret_mode
 
 _ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
 _PARITY = np.uint32(0x1BD11BDA)
@@ -66,11 +70,11 @@ def _drange_kernel(seed_ref, out_ref, *, block_elems: int):
 
 
 def random_u32(seed: jax.Array, n_rows: int, n_cols: int,
-               *, block_rows: int = 256, interpret: bool = False) -> jax.Array:
+               *, block_rows: int = 256,
+               interpret: bool | None = None) -> jax.Array:
     """Generate (n_rows, n_cols) uint32 randoms from a (2,) uint32 seed."""
     br = min(block_rows, n_rows)
     grid = (pl.cdiv(n_rows, br),)
-    import functools
     kernel = functools.partial(_drange_kernel, block_elems=br * n_cols)
     return pl.pallas_call(
         kernel,
@@ -78,5 +82,5 @@ def random_u32(seed: jax.Array, n_rows: int, n_cols: int,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((br, n_cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rows, n_cols), jnp.uint32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(seed.astype(jnp.uint32))

@@ -9,12 +9,11 @@ import jax.numpy as jnp
 
 from . import drange, ref
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "n_cols", "use_pallas", "interpret"))
 def pim_random_u32(seed: jax.Array, n_rows: int, n_cols: int,
-                   *, use_pallas: bool = False, interpret: bool = not _ON_TPU) -> jax.Array:
+                   *, use_pallas: bool = False,
+                   interpret: bool | None = None) -> jax.Array:
     if use_pallas:
         return drange.random_u32(seed, n_rows, n_cols, interpret=interpret)
     return ref.random_u32(seed, n_rows, n_cols)
@@ -22,7 +21,8 @@ def pim_random_u32(seed: jax.Array, n_rows: int, n_cols: int,
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "n_cols", "use_pallas", "interpret"))
 def pim_random_uniform(seed: jax.Array, n_rows: int, n_cols: int,
-                       *, use_pallas: bool = False, interpret: bool = not _ON_TPU) -> jax.Array:
+                       *, use_pallas: bool = False,
+                       interpret: bool | None = None) -> jax.Array:
     """Uniform floats in [0, 1) from the top 24 bits."""
     u = pim_random_u32(seed, n_rows, n_cols, use_pallas=use_pallas, interpret=interpret)
     return (u >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24))

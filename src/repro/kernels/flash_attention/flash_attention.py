@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import interpret_mode
+
 _NEG_INF = -1e30
 
 
@@ -88,7 +90,11 @@ def _flash_kernel(*refs, sm_scale: float, causal: bool, bq: int, bk: int,
         chunk_ok = cc < len_ref[b]
         if causal:
             chunk_ok = chunk_ok & (cc <= row)
-        mask = mask & jnp.where(col < seq_prefix, col < plen_ref[b], chunk_ok)
+        # boolean algebra, not a select: Mosaic cannot select between
+        # two i1 vectors (it truncates an i8 select to i1)
+        in_prefix = col < seq_prefix
+        mask = mask & ((in_prefix & (col < plen_ref[b]))
+                       | (~in_prefix & chunk_ok))
     else:
         if has_lengths:
             mask = mask & (col < len_ref[b])             # per-sequence length
@@ -122,7 +128,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     k_prefix: jax.Array | None = None,
                     v_prefix: jax.Array | None = None,
                     prefix_lengths: jax.Array | None = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Fused attention forward.
 
     q: (B, H, Sq, D);  k, v: (B, KVH, Sk, D) with H % KVH == 0.
@@ -192,7 +198,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(q, k, v)
     else:
         # lengths (and, on the chunked path, prefix_lengths) ride as
@@ -212,6 +218,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             kernel,
             grid_spec=grid_spec,
             out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(*scalars, q, k, v)
     return out[:, :, :sq]

@@ -14,8 +14,6 @@ import jax
 
 from . import flash_attention as fa, ref
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 def attention_inline(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      causal: bool = True, sm_scale: float | None = None,
@@ -25,7 +23,7 @@ def attention_inline(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      v_prefix: jax.Array | None = None,
                      prefix_lengths: jax.Array | None = None,
                      use_pallas: bool = True,
-                     interpret: bool = not _ON_TPU) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """Pallas-or-reference dispatch; see the kernel for the contract.
 
     ``lengths`` (B,) masks key columns at or beyond each sequence's

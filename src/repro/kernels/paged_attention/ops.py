@@ -26,15 +26,13 @@ import jax
 
 from . import paged_attention as pa, ref
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 def paged_attention_inline(q: jax.Array, k_arena: jax.Array,
                            v_arena: jax.Array, block_tables: jax.Array,
                            lengths: jax.Array, *,
                            sm_scale: float | None = None,
                            use_pallas: bool = True,
-                           interpret: bool = not _ON_TPU,
+                           interpret: bool | None = None,
                            k_self: jax.Array | None = None,
                            v_self: jax.Array | None = None,
                            return_lse: bool = False):

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import interpret_mode
+
 _NEG_INF = -1e30
 
 
@@ -106,7 +108,9 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
             vs = vs_ref[0].astype(jnp.float32)
             kvh = ks.shape[0]
             qg = q.reshape(kvh, groups, d)
-            s_self = jnp.einsum("kgd,kd->kg", qg, ks).reshape(h, 1)
+            # an elementwise product reduced over the lane dim: Mosaic
+            # lays the (KVH, G) result out as it does the page scores
+            s_self = jnp.sum(qg * ks[:, None, :], axis=2).reshape(h, 1)
             m_new = jnp.maximum(m, s_self)
             alpha = jnp.exp(m - m_new)
             p_self = jnp.exp(s_self - m_new)                 # (H, 1)
@@ -117,14 +121,14 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
         if return_lse:
-            m_ref[0] = m[:, 0]
-            l_ref[0] = l[:, 0]
+            m_ref[0] = m
+            l_ref[0] = l
 
 
 def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
                     block_tables: jax.Array, lengths: jax.Array, *,
                     sm_scale: float | None = None,
-                    interpret: bool = False,
+                    interpret: bool | None = None,
                     k_self: jax.Array | None = None,
                     v_self: jax.Array | None = None,
                     return_lse: bool = False):
@@ -167,8 +171,10 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
     out_specs = pl.BlockSpec((1, h, d), lambda b, p, bt, ln: (b, 0, 0))
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     if return_lse:
-        lse_spec = pl.BlockSpec((1, h), lambda b, p, bt, ln: (b, 0))
-        lse_shape = jax.ShapeDtypeStruct((bsz, h), jnp.float32)
+        # (B, H, 1): a (1, H) block over (B, H) would have a unit
+        # second-minor dim that is not the array's
+        lse_spec = pl.BlockSpec((1, h, 1), lambda b, p, bt, ln: (b, 0, 0))
+        lse_shape = jax.ShapeDtypeStruct((bsz, h, 1), jnp.float32)
         out_specs = [out_specs, lse_spec, lse_spec]
         out_shape = [out_shape, lse_shape, lse_shape]
 
@@ -187,8 +193,9 @@ def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
     if return_lse:
-        return tuple(out)
+        o, m, l = out
+        return o, m[..., 0], l[..., 0]
     return out

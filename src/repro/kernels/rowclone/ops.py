@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the RowClone kernel family.
 
-``use_pallas`` selects the Pallas kernel (TPU target; interpret-mode on
-CPU) vs the pure-jnp reference.  Distribution-level code (dry-run, train,
+``use_pallas`` selects the Pallas kernel vs the pure-jnp reference;
+``interpret=None`` lets the backend decide how the kernel runs
+(``repro.kernels.common``).  Distribution-level code (dry-run, train,
 serve) defaults to the jnp path — XLA already emits a fused copy/memset
 for it — while the Pallas path is the TPU hot-spot implementation
 validated against the reference in tests.
@@ -16,11 +17,10 @@ import jax.numpy as jnp
 
 from . import ref, rowclone
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
-def pim_copy(src: jax.Array, *, use_pallas: bool = False, interpret: bool = not _ON_TPU) -> jax.Array:
+def pim_copy(src: jax.Array, *, use_pallas: bool = False,
+             interpret: bool | None = None) -> jax.Array:
     """Bulk copy. 2D inputs stream through the Pallas kernel; other ranks
     reshape to 2D first (row-major pages)."""
     if not use_pallas:
@@ -32,7 +32,7 @@ def pim_copy(src: jax.Array, *, use_pallas: bool = False, interpret: bool = not 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "use_pallas", "interpret"))
 def pim_init(shape, value, dtype=jnp.float32, *, use_pallas: bool = False,
-             interpret: bool = not _ON_TPU) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     if not use_pallas:
         return ref.init_2d(shape, value, dtype)
     import numpy as np
@@ -43,7 +43,7 @@ def pim_init(shape, value, dtype=jnp.float32, *, use_pallas: bool = False,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"), donate_argnums=(0,))
 def pim_page_copy(arena: jax.Array, src_pages: jax.Array, dst_pages: jax.Array,
-                  *, use_pallas: bool = False, interpret: bool = not _ON_TPU) -> jax.Array:
+                  *, use_pallas: bool = False, interpret: bool | None = None) -> jax.Array:
     """RowClone page copy inside a donated arena buffer."""
     if not use_pallas:
         return ref.page_copy(arena, src_pages, dst_pages)
@@ -52,7 +52,7 @@ def pim_page_copy(arena: jax.Array, src_pages: jax.Array, dst_pages: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"), donate_argnums=(0,))
 def pim_page_init(arena: jax.Array, dst_pages: jax.Array, value,
-                  *, use_pallas: bool = False, interpret: bool = not _ON_TPU) -> jax.Array:
+                  *, use_pallas: bool = False, interpret: bool | None = None) -> jax.Array:
     if not use_pallas:
         return ref.page_init(arena, dst_pages, value)
     return rowclone.page_init(arena, dst_pages, value, interpret=interpret)
@@ -60,45 +60,41 @@ def pim_page_init(arena: jax.Array, dst_pages: jax.Array, value,
 
 # ------------------------------------------------------------------ #
 # Layer-batched launches — the batched PiM op scheduler's primitives.
-# Arenas may carry arbitrary trailing dims: (L, P, ...) is flattened to
-# (L, P, E) for the kernel and restored on return.  An empty op batch is
-# a no-op (no launch at all; the scheduler never dispatches for it).
+# Arenas keep their natural trailing dims, (L, P, *page): the kernels
+# move a page as one block of that shape.  An empty op batch is a no-op
+# (no launch at all; the scheduler never dispatches for it).
 # ------------------------------------------------------------------ #
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"), donate_argnums=(0,))
 def pim_page_copy_batched(arena: jax.Array, src_pages: jax.Array,
                           dst_pages: jax.Array, *, use_pallas: bool = False,
-                          interpret: bool = not _ON_TPU) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """Copy ``arena[:, src_pages] -> arena[:, dst_pages]`` across all
     layers in one fused launch.  arena: (layers, pages, ...)."""
     if src_pages.shape[0] == 0:
         return arena
     if not use_pallas:
         return ref.page_copy_batched(arena, src_pages, dst_pages)
-    L, P = arena.shape[:2]
-    out = rowclone.page_copy_batched(arena.reshape(L, P, -1), src_pages,
-                                     dst_pages, interpret=interpret)
-    return out.reshape(arena.shape)
+    return rowclone.page_copy_batched(arena, src_pages, dst_pages,
+                                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"), donate_argnums=(0,))
 def pim_page_init_batched(arena: jax.Array, dst_pages: jax.Array, value,
                           *, use_pallas: bool = False,
-                          interpret: bool = not _ON_TPU) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     if dst_pages.shape[0] == 0:
         return arena
     if not use_pallas:
         return ref.page_init_batched(arena, dst_pages, value)
-    L, P = arena.shape[:2]
-    out = rowclone.page_init_batched(arena.reshape(L, P, -1), dst_pages,
-                                     value, interpret=interpret)
-    return out.reshape(arena.shape)
+    return rowclone.page_init_batched(arena, dst_pages, value,
+                                      interpret=interpret)
 
 
 def kv_scatter_inline(arena: jax.Array, pages: jax.Array, slots: jax.Array,
                       new: jax.Array, *, use_pallas: bool = False,
-                      interpret: bool = not _ON_TPU) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """Write ``arena[:, pages[b], slots[b]] <- new[:, b]`` in one launch.
 
     arena: (layers, pages, page_size, ...); new: (layers, batch, ...).
@@ -108,15 +104,10 @@ def kv_scatter_inline(arena: jax.Array, pages: jax.Array, slots: jax.Array,
     """
     if pages.shape[0] == 0:
         return arena
-    L, P, S = arena.shape[:3]
-    B = pages.shape[0]
-    a4 = arena.reshape(L, P, S, -1)
-    n3 = new.reshape(L, B, -1)
+    new = new.reshape((arena.shape[0], pages.shape[0]) + arena.shape[3:])
     if not use_pallas:
-        out = ref.kv_scatter(a4, pages, slots, n3)
-    else:
-        out = rowclone.kv_scatter(a4, pages, slots, n3, interpret=interpret)
-    return out.reshape(arena.shape)
+        return ref.kv_scatter(arena, pages, slots, new)
+    return rowclone.kv_scatter(arena, pages, slots, new, interpret=interpret)
 
 
 pim_kv_scatter = functools.partial(
@@ -138,7 +129,4 @@ def kv_gather_inline(arena: jax.Array, pages: jax.Array,
     the gather into the surrounding step); only mutations are RowClone
     hot spots.
     """
-    L, P, S = arena.shape[:3]
-    a4 = arena.reshape(L, P, S, -1)
-    out = ref.kv_gather(a4, pages, slots)
-    return out.reshape((L, pages.shape[0]) + arena.shape[3:])
+    return ref.kv_gather(arena, pages, slots)

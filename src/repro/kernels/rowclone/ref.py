@@ -40,11 +40,11 @@ def page_init_batched(arena: jax.Array, dst_pages: jax.Array, value) -> jax.Arra
 
 def kv_scatter(arena: jax.Array, pages: jax.Array, slots: jax.Array,
                new: jax.Array) -> jax.Array:
-    """arena: (L, P, S, E); pages/slots: (B,); new: (L, B, E)."""
+    """arena: (L, P, S, *slot); pages/slots: (B,); new: (L, B, *slot)."""
     return arena.at[:, pages, slots].set(new.astype(arena.dtype))
 
 
 def kv_gather(arena: jax.Array, pages: jax.Array, slots: jax.Array) -> jax.Array:
     """Read back ``arena[:, pages[b], slots[b]]`` — the scatter's inverse.
-    arena: (L, P, S, E); pages/slots: (B,).  Returns (L, B, E)."""
+    arena: (L, P, S, *slot); pages/slots: (B,).  Returns (L, B, *slot)."""
     return arena[:, pages, slots]

@@ -1,11 +1,12 @@
 """Jit'd public wrappers for the SSM state-arena op family.
 
 State arenas are ``(groups, sublayers, slots, ...)`` — conv windows and
-SSD states keep their natural trailing dims; the wrappers flatten to the
-kernels' ``(L, R, E)`` form and restore on return.  As with RowClone,
-``use_pallas`` selects the Pallas kernel (TPU target; interpret-mode on
-CPU) vs the pure-jnp reference, and an empty op batch is a no-op (no
-launch; the scheduler never dispatches for it).
+SSD states keep their natural trailing dims; the wrappers merge the two
+leading dims into the kernels' layer axis, ``(L, R, *row)``, and restore
+on return.  As with RowClone, ``use_pallas`` selects the Pallas kernel
+vs the pure-jnp reference (``interpret=None`` lets the backend decide
+how the kernel runs), and an empty op batch is a no-op (no launch; the
+scheduler never dispatches for it).
 
 Row copy/init reuse the RowClone ``page_copy_batched`` /
 ``page_init_batched`` kernels — a state row is just a page of the
@@ -24,18 +25,15 @@ from repro.kernels.rowclone import rowclone as rc_kernels
 
 from . import ref, ssm_scan
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 def _flat3(a: jax.Array) -> jax.Array:
-    """(groups, sublayers, slots, ...) -> (groups*sublayers, slots, E)."""
-    G, M, R = a.shape[:3]
-    return a.reshape(G * M, R, -1)
+    """(groups, sublayers, slots, *row) -> (groups*sublayers, slots, *row)."""
+    return a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
 
 
 def state_scatter_inline(arena: jax.Array, rows: jax.Array,
                          new: jax.Array, *, use_pallas: bool = False,
-                         interpret: bool = not _ON_TPU) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """Write ``arena[:, :, rows[b]] <- new[:, :, b]`` in one launch.
 
     arena: (groups, sublayers, slots, ...); new: (groups, sublayers,
@@ -46,7 +44,7 @@ def state_scatter_inline(arena: jax.Array, rows: jax.Array,
     if rows.shape[0] == 0:
         return arena
     a3 = _flat3(arena)
-    n3 = new.reshape(a3.shape[0], rows.shape[0], -1)
+    n3 = new.reshape((a3.shape[0], rows.shape[0]) + a3.shape[2:])
     if not use_pallas:
         out = ref.state_scatter(a3, rows, n3)
     else:
@@ -71,7 +69,7 @@ def state_gather_inline(arena: jax.Array, rows: jax.Array) -> jax.Array:
                    donate_argnums=(0,))
 def pim_state_copy(arena: jax.Array, src_rows: jax.Array,
                    dst_rows: jax.Array, *, use_pallas: bool = False,
-                   interpret: bool = not _ON_TPU) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Copy-on-fork: ``arena[:, :, src_rows[i]] -> arena[:, :, dst_rows[i]]``
     across every sublayer in one RowClone launch."""
     if src_rows.shape[0] == 0:
@@ -89,7 +87,7 @@ def pim_state_copy(arena: jax.Array, src_rows: jax.Array,
                    donate_argnums=(0,))
 def pim_state_init(arena: jax.Array, dst_rows: jax.Array, value,
                    *, use_pallas: bool = False,
-                   interpret: bool = not _ON_TPU) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Init-on-free: memset ``arena[:, :, dst_rows[i]] <- value`` in one
     RowClone-Init launch (no cross-sequence state leakage)."""
     if dst_rows.shape[0] == 0:

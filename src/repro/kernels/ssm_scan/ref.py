@@ -1,9 +1,9 @@
 """Pure-jnp references for the SSM state-arena ops.
 
-A state arena is ``(groups, sublayers, slots, elems)`` after the ops
-layer flattens trailing dims — ``groups * sublayers`` is the "layer"
+A state arena is ``(groups * sublayers, slots, *row)`` after the ops
+layer merges its leading dims — ``groups * sublayers`` is the "layer"
 axis a launch streams over, ``slots`` the per-sequence state rows.  The
-references here work on the flattened 3D ``(L, R, E)`` form.
+references work on any trailing row shape.
 """
 
 from __future__ import annotations
@@ -14,15 +14,15 @@ import jax.numpy as jnp
 
 def state_scatter(arena: jax.Array, rows: jax.Array,
                   new: jax.Array) -> jax.Array:
-    """``arena[:, rows[b]] <- new[:, b]``.  arena: (L, R, E); rows: (B,);
-    new: (L, B, E).  Duplicate rows carry identical payloads by the
+    """``arena[:, rows[b]] <- new[:, b]``.  arena: (L, R, *row); rows:
+    (B,); new: (L, B, *row).  Duplicate rows carry identical payloads by the
     caller's contract (padded batches duplicate row 0), so scatter order
     does not matter."""
     return arena.at[:, rows].set(new.astype(arena.dtype))
 
 
 def state_gather(arena: jax.Array, rows: jax.Array) -> jax.Array:
-    """``arena[:, rows[b]]`` -> (L, B, E) — the scatter's inverse."""
+    """``arena[:, rows[b]]`` -> (L, B, *row) — the scatter's inverse."""
     return arena[:, rows]
 
 

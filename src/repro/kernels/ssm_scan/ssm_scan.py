@@ -11,7 +11,7 @@ model writes in ONE launch, the same amortization argument as
 ``rowclone.kv_scatter``.
 
 Row copy (copy-on-fork) and row init (init-on-free) do not get their own
-kernels: a state row IS a page of a ``(L, R, E)`` arena, so they ride the
+kernels: a state row IS a page of a ``(L, R, *row)`` arena, so they ride the
 existing RowClone ``page_copy_batched`` / ``page_init_batched`` kernels
 (see ``ops.py``) — which is the point: fork and free traffic is RowClone
 traffic, priced as such on the model-face replay.
@@ -24,40 +24,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import interpret_mode, page_spec, paged_view
+
 
 def _state_scatter_kernel(row_idx_ref, new_ref, arena_ref, out_ref):
-    # Grid: (layers, batch).  The output BlockSpec lands this (1,1,E)
+    # Grid: (layers, batch, parts).  The output BlockSpec lands this
     # block exactly on arena[l, rows[b]]; the body is a pure row write.
     del row_idx_ref, arena_ref
-    out_ref[...] = new_ref[...].reshape(out_ref.shape)
+    out_ref[...] = new_ref[...]
 
 
 def state_scatter(arena: jax.Array, rows: jax.Array, new: jax.Array,
-                  *, interpret: bool = False) -> jax.Array:
+                  *, interpret: bool | None = None) -> jax.Array:
     """Scatter fresh state rows: ``arena[l, rows[b]] <- new[l, b]``.
 
-    arena: (layers, num_rows, elems); rows: (batch,) int32; new:
-    (layers, batch, elems).  One launch covers every sublayer's state
-    for every sequence in the round.  Duplicate rows are only valid with
-    identical payloads (last grid iteration wins).
+    arena: (layers, num_rows, *row); rows: (batch,) int32; new:
+    (layers, batch, *row).  A row keeps its natural trailing dims and
+    moves as one block (split along its leading dim when too large for
+    VMEM), so any row shape compiles.  One launch covers every
+    sublayer's state for every sequence in the round.  Duplicate rows
+    are only valid with identical payloads (last grid iteration wins).
     """
-    layers, num_rows, elems = arena.shape
-    batch = rows.shape[0]
-    grid = (layers, batch)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, elems), lambda l, b, rw: (l, b, 0)),  # new
-            pl.BlockSpec(memory_space=pl.ANY),     # arena (aliased, unread)
-        ],
-        out_specs=pl.BlockSpec((1, 1, elems),
-                               lambda l, b, rw: (l, rw[b], 0)),
-    )
-    return pl.pallas_call(
+    a, n = paged_view(arena), paged_view(new.astype(arena.dtype))
+    new_spec, parts = page_spec(n, lambda b, rw: b)
+    out_spec, _ = page_spec(a, lambda b, rw: rw[b])
+    out = pl.pallas_call(
         _state_scatter_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(a.shape[0], rows.shape[0], parts),
+            in_specs=[
+                new_spec,
+                pl.BlockSpec(memory_space=pl.ANY),   # arena (aliased, unread)
+            ],
+            out_specs=out_spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         input_output_aliases={2: 0},  # arena (after 1 prefetch + new) -> out
-        interpret=interpret,
-    )(rows.astype(jnp.int32), new.astype(arena.dtype), arena)
+        interpret=interpret_mode(interpret),
+    )(rows.astype(jnp.int32), n, a)
+    return out.reshape(arena.shape)

@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract memory/cost/collective evidence.
 
-The two lines above MUST precede any jax import (jax locks the device
-count at first init); do not move them.
+The production meshes are 512 host-platform (CPU) devices, set up by
+:func:`use_host_devices` before anything initialises a JAX backend
+(jax locks the device count at first init, not at import).
 
 Usage:
   python -m repro.launch.dryrun --arch granite-3-8b --shape train_4k
@@ -18,6 +16,7 @@ Artifacts: experiments/dryrun/<arch>__<shape>__<mesh>.json
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import time
@@ -472,7 +471,17 @@ def all_cells(multi_pod_too: bool = True):
     return cells
 
 
+def use_host_devices(n: int = 512) -> None:
+    """Compile on ``n`` host-platform devices, the production meshes'
+    count.  Call before anything initialises a JAX backend; the sweep's
+    child processes inherit the setting."""
+    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main() -> int:
+    use_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -2,7 +2,7 @@
 front door, measured as goodput-under-SLO.
 
   PYTHONPATH=src python -m repro.launch.serve_async --arch granite-3-8b \
-      --rates 2,8,32 --requests 24 --ttft-slo-ms 500
+      --reduced --rates 2,8,32 --requests 24 --ttft-slo-ms 500
 
 Closed-loop drivers (``repro.launch.serve``) understate tail latency:
 the next request only arrives when the last one finished, so the system
@@ -106,22 +106,23 @@ def shared_prefix_prompts(n: int, vocab: int, *, prefix_len: int,
 
 
 async def _amain(args) -> None:
-    import jax
-    from repro.configs import ARCHS, reduced
-    from repro.models import transformer as T
-    from repro.models.params import init_params
-    from repro.serving.engine import PagedEngine, Request
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.serve import build_engine, serving_config
+    from repro.serving.engine import Request
     from repro.serving.trace import replay_on_device
 
-    cfg = reduced(ARCHS[args.arch])
-    params = init_params(T.model_defs(cfg), jax.random.PRNGKey(0))
+    enable_compile_cache()
+    cfg = serving_config(args.arch, layers=args.layers,
+                         reduced_widths=args.reduced)
     rates = [float(r) for r in args.rates.split(",")]
     out = []
+    params = None
     for rate in rates:
-        engine = PagedEngine(cfg, params, page_size=args.page_size,
-                             num_pages=args.num_pages,
-                             max_prefill_chunk=args.chunk,
-                             prefix_cache=True, record_trace=True)
+        engine = build_engine(cfg, params=params, page_size=args.page_size,
+                              num_pages=args.num_pages,
+                              max_prefill_chunk=args.chunk,
+                              prefix_cache=True, record_trace=True)
+        params = engine.params
         # warm the compile caches outside the timed trace
         engine.submit(Request(10**6, np.arange(args.prefix_len + args.tail_len)
                               % cfg.vocab_size, max_new_tokens=2))
@@ -152,6 +153,11 @@ async def _amain(args) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers (widths stay "
+                         "as published)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="toy widths (d_model 128, vocab 512) for CPU runs")
     ap.add_argument("--rates", default="2,8,32",
                     help="comma-separated Poisson arrival rates (req/s)")
     ap.add_argument("--requests", type=int, default=24)

@@ -30,7 +30,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoEConfig, ParallelConfig
@@ -169,13 +168,13 @@ def moe_layer(cfg: ModelConfig, pcfg: ParallelConfig, p: Dict[str, jax.Array],
                 _local_moe, top_k=m.top_k, num_experts=m.num_experts,
                 capacity=cap, activation=cfg.activation, model_axis=model_ax,
                 psum_dtype=psum_dtype)
-            out, aux = shard_map(
+            out, aux = jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(P(*batch_spec, None, None), P(None, None),
                           P(model_ax, None, None), P(model_ax, None, None),
                           P(model_ax, None, None)),
                 out_specs=(P(*batch_spec, None, None), P()),
-                check_rep=False,
+                check_vma=False,
             )(x, p["router"].astype(jnp.float32), cast(p["w_gate"]),
               cast(p["w_up"]), cast(p["w_down"]))
             aux = jnp.mean(aux)

@@ -179,11 +179,10 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig
-from repro.distributed.compression import _axis_size, psum_compressed
+from repro.distributed.compression import psum_compressed
 from repro.distributed.sharding import sharding_env
 from repro.kernels.drange import ops as dr_ops
 from repro.kernels.flash_attention import ops as fa_ops
@@ -210,6 +209,12 @@ class Request:
     eos_token_id: Optional[int] = None
     share_with: Optional[int] = None      # prefix sharing source
     shared_len: int = 0
+    # keep_logits: store in ``logits`` the (vocab,) logits that chose
+    # out_tokens[0] — the prefill's last prompt position, left on the
+    # device — for callers that check the model's output, not only its
+    # tokens
+    keep_logits: bool = False
+    logits: Optional[jax.Array] = None
     out_tokens: List[int] = field(default_factory=list)
     done: bool = False
 
@@ -241,13 +246,18 @@ class _ChunkPrefill:
         return len(self.req.prompt) - self.off
 
 
+def _keep_logits(req: Request, logits: jax.Array, row: int) -> None:
+    """Store row ``row`` of a prefill's logits on a request that asked."""
+    if req.keep_logits:
+        req.logits = logits[row]
+
+
 class PagedEngine:
     """Single-host engine for GQA decoder-only models (the paged path)."""
 
     def __init__(self, cfg: ModelConfig, params, *, page_size: int = 16,
                  num_pages: int = 256, pcfg: Optional[ParallelConfig] = None,
-                 seed: int = 0, use_pallas: bool = False,
-                 interpret: Optional[bool] = None, fused: bool = True,
+                 seed: int = 0, use_pallas: bool = False, fused: bool = True,
                  fused_prefill: bool = True,
                  max_prefill_chunk: Optional[int] = None,
                  decode_block_rounds: int = 1, mixed_rounds: bool = True,
@@ -323,11 +333,10 @@ class PagedEngine:
                                   page_size=page_size, use_pallas=use_pallas,
                                   lib=lib, record_trace=record_trace,
                                   mesh=mesh, prefix_cache=prefix_cache)
+        # Pallas kernels (True) or their jnp references; how a kernel
+        # runs — compiled on a TPU, interpreted elsewhere — is the
+        # backend's call (repro.kernels.common.interpret_mode)
         self.use_pallas = use_pallas
-        # interpret-mode plumbing (was hardcoded True): default follows
-        # the backend — compiled kernels on TPU, interpreter elsewhere
-        self.interpret = ((jax.default_backend() != "tpu")
-                          if interpret is None else interpret)
         self.fused = fused
         self.fused_prefill = fused_prefill
         if max_prefill_chunk is not None and max_prefill_chunk < 1:
@@ -532,21 +541,21 @@ class PagedEngine:
     def _shard_wrap(self, fn, n_args, arena_at, n_extra_out=1):
         """Wrap a fused step fn as a shard_map program over the mesh:
         one dispatch spanning every device.  Outputs are ``n_extra_out``
-        replicated values (tokens — identical on every shard, the final
-        logit reduce and sampling run replicated) followed by the two
-        sharded arenas and the (conv, ssm) state outputs — always None
+        replicated values (tokens and logits — identical on every shard,
+        the final logit reduce and sampling run replicated) followed by
+        the two sharded arenas and the (conv, ssm) state outputs — always None
         under a mesh (the constructor rejects mesh+SSM), so their P()
-        specs map over zero leaves.  ``check_rep=False``: the
+        specs map over zero leaves.  ``check_vma=False``: the
         collectives guarantee the replication the spec claims; jax's
         checker cannot see through the masked gathers."""
         out_specs = (P(),) * n_extra_out + (self._arena_spec,
                                             self._arena_spec, P(), P())
-        return shard_map(fn, mesh=self.mesh,
-                         in_specs=self._sharded_specs(n_args, arena_at),
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=self._sharded_specs(n_args, arena_at),
+                             out_specs=out_specs, check_vma=False)
 
     def _step_kwargs(self):
-        kw = dict(use_pallas=self.use_pallas, interpret=self.interpret)
+        kw = dict(use_pallas=self.use_pallas)
         if self.mesh is not None:
             kw.update(axis="model", compressed=self.compressed_collectives)
         return kw
@@ -593,7 +602,7 @@ class PagedEngine:
                                    has_writes=has_writes,
                                    **eng._step_kwargs())
             if eng.mesh is not None:
-                fn = eng._shard_wrap(fn, 13, (3, 4))
+                fn = eng._shard_wrap(fn, 13, (3, 4), n_extra_out=2)
             return fn(params, toks, lens, k_arena, v_arena,
                       pages, slots, src, seed, temps, conv_arena,
                       ssm_arena, srows)
@@ -619,7 +628,7 @@ class PagedEngine:
                                    eng.pcfg, has_writes=has_writes,
                                    **eng._step_kwargs())
             if eng.mesh is not None:
-                fn = eng._shard_wrap(fn, 16, (4, 5))
+                fn = eng._shard_wrap(fn, 16, (4, 5), n_extra_out=2)
             return fn(params, toks, lens, offs, k_arena, v_arena, bt,
                       plens, pages, slots, src, seed, temps, conv_arena,
                       ssm_arena, srows)
@@ -674,7 +683,7 @@ class PagedEngine:
                                    has_writes=has_writes,
                                    **eng._step_kwargs())
             if eng.mesh is not None:
-                fn = eng._shard_wrap(fn, 25, (4, 5), n_extra_out=2)
+                fn = eng._shard_wrap(fn, 25, (4, 5), n_extra_out=3)
             return fn(params, c_toks, c_lens, c_offs, k_arena, v_arena,
                       c_bt, c_plens, c_pages, c_slots, c_src, c_seed,
                       c_temps, d_last, d_bt, d_lens, d_pages, d_slots,
@@ -878,8 +887,8 @@ class PagedEngine:
             "n_valid": n_valid, "rids": rids,
         }
 
-    def _finish_chunks(self, batch: List[tuple],
-                       tokens) -> List[_ChunkPrefill]:
+    def _finish_chunks(self, batch: List[tuple], tokens,
+                       logits) -> List[_ChunkPrefill]:
         """Advance chunk offsets; rows whose chunk completed the prompt
         consume their first token (one lazy host transfer per batch) and
         join the active set.  Returns the still-unfinished states."""
@@ -891,6 +900,7 @@ class PagedEngine:
                 if toks_np is None:         # the batch's one host transfer
                     toks_np = np.asarray(tokens)
                 st.req.out_tokens.append(int(toks_np[i]))
+                _keep_logits(st.req, logits, i)
                 self.active[st.req.req_id] = st.req
                 self.stats["prefills"] += 1
                 del self._chunk_by_id[st.req.req_id]
@@ -916,7 +926,7 @@ class PagedEngine:
         srows, conv, ssm = self._state_operands(c["rids"])
         self.rng_ctr += 1
         seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        tokens, k_arena, v_arena, conv_a, ssm_a = self._chunk_step(
+        tokens, logits, k_arena, v_arena, conv_a, ssm_a = self._chunk_step(
             self.params, c["toks"], c["lens"], c["offs"],
             self.cache.k_arena, self.cache.v_arena, c["bt"], c["plens"],
             c["pages"], c["slots"], c["src"], seed, c["temps"],
@@ -933,7 +943,7 @@ class PagedEngine:
                 [st.req.req_id for st, _ in batch])
         self.stats["prefill_chunks"] += len(batch)
         self.stats["fused_prefill_dispatches"] += 1
-        return self._finish_chunks(batch, tokens)
+        return self._finish_chunks(batch, tokens, logits)
 
     def _mixed_round(self, batch: List[tuple], sc: int,
                      d_rids: List[int]) -> List[_ChunkPrefill]:
@@ -990,7 +1000,7 @@ class PagedEngine:
         c_seed = self.rng_seed + jnp.uint32(self.rng_ctr)
         self.rng_ctr += 1
         d_seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        c_tokens, d_tokens, k_arena, v_arena, conv_a, ssm_a = \
+        c_tokens, c_logits, d_tokens, k_arena, v_arena, conv_a, ssm_a = \
             self._mixed_step(
                 self.params, c["toks"], c["lens"], c["offs"],
                 self.cache.k_arena, self.cache.v_arena, c["bt"],
@@ -1017,7 +1027,7 @@ class PagedEngine:
         self.cache.queue.count_external("fused_mixed")
         self.stats["prefill_chunks"] += len(batch)
         self.stats["mixed_dispatches"] += 1
-        unfinished = self._finish_chunks(batch, c_tokens)
+        unfinished = self._finish_chunks(batch, c_tokens, c_logits)
         d_toks = np.asarray(d_tokens)[:B]
         emitted = 0
         for i, rid in enumerate(d_rids):
@@ -1082,7 +1092,7 @@ class PagedEngine:
             [reqs[i].req_id for i in idx])
         self.rng_ctr += 1
         seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        tokens, k_arena, v_arena, conv_a, ssm_a = self._prefill_step(
+        tokens, logits, k_arena, v_arena, conv_a, ssm_a = self._prefill_step(
             self.params, jnp.asarray(toks), jnp.asarray(lens),
             self.cache.k_arena, self.cache.v_arena,
             jnp.asarray(pages, jnp.int32), jnp.asarray(slots, jnp.int32),
@@ -1097,6 +1107,7 @@ class PagedEngine:
         toks_np = np.asarray(tokens)[:B]    # the batch's one host transfer
         for i, r in enumerate(reqs):
             r.out_tokens.append(int(toks_np[i]))
+            _keep_logits(r, logits, i)
             self.active[r.req_id] = r
             self.stats["prefills"] += 1
             self.cache.commit_prefix(r.req_id, r.prompt)
@@ -1138,6 +1149,7 @@ class PagedEngine:
             st.write([req.req_id], conv, ssm)
         tok = self._sample(logits[:, -1], req.temperature)
         req.out_tokens.append(int(tok[0]))
+        _keep_logits(req, logits[:, -1], 0)
         self.active[req.req_id] = req
         self.stats["prefills"] += 1
         self.cache.commit_prefix(req.req_id, req.prompt)
@@ -1323,7 +1335,7 @@ class PagedEngine:
         logits, k_new, v_new, conv_new, ssm_new = _eager_decode_forward(
             self.cfg, self.pcfg, self.params, last, self.cache.k_arena,
             self.cache.v_arena, bt, lens, use_pallas=self.use_pallas,
-            interpret=self.interpret, conv_arena=conv_arena,
+            conv_arena=conv_arena,
             ssm_arena=ssm_arena, srows=srows)
         if self._has_attn:
             # account the per-layer jitted paged-attention dispatches
@@ -1354,8 +1366,7 @@ class PagedEngine:
         self.rng_ctr += 1
         seed = self.rng_seed + jnp.uint32(self.rng_ctr)
         toks = _select_tokens(logits[:, 0], temps, seed,
-                              use_pallas=self.use_pallas,
-                              interpret=self.interpret)
+                              use_pallas=self.use_pallas)
         return np.asarray(toks)            # one host transfer
 
     def _sample(self, logits: jax.Array, temperature: float) -> np.ndarray:
@@ -1366,8 +1377,7 @@ class PagedEngine:
         seed = self.rng_seed + jnp.uint32(self.rng_ctr)
         temps = jnp.full((logits.shape[0],), temperature, jnp.float32)
         return np.asarray(_select_tokens(logits, temps, seed,
-                                         use_pallas=self.use_pallas,
-                                         interpret=self.interpret))
+                                         use_pallas=self.use_pallas))
 
 
 # ---------------------------------------------------------------------- #
@@ -1378,7 +1388,8 @@ class PagedEngine:
 def _fused_decode_step(cfg, pcfg, params, last, k_arena, v_arena, bt, lens,
                        pages, slots, seed, temps, conv_arena=None,
                        ssm_arena=None, srows=None, *, use_pallas: bool,
-                       interpret: bool, axis: Optional[str] = None,
+                       interpret: Optional[bool] = None,
+                       axis: Optional[str] = None,
                        compressed: bool = False):
     """Forward (scan over layers) + KV scatter + token selection: the
     whole decode round as one compiled program over donated arenas.
@@ -1411,7 +1422,8 @@ def _fused_decode_step(cfg, pcfg, params, last, k_arena, v_arena, bt, lens,
 def _fused_block_step(cfg, pcfg, params, last, steps, k_arena, v_arena, bt,
                       lens, pages, slots, eos, seed, temps, rowmap,
                       conv_arena=None, ssm_arena=None, srows=None, *,
-                      use_pallas: bool, interpret: bool,
+                      use_pallas: bool,
+                      interpret: Optional[bool] = None,
                       axis: Optional[str] = None, compressed: bool = False):
     """Up to K decode rounds as ONE compiled program: a ``while_loop``
     whose carry holds the per-row state a round-at-a-time host loop
@@ -1494,7 +1506,8 @@ def _fused_mixed_step(cfg, pcfg, params, c_toks, c_lens, c_offs, k_arena,
                       d_slots, d_seed, d_temps, d_from_chunk,
                       conv_arena=None, ssm_arena=None, c_srows=None,
                       d_srows=None, *, has_writes: bool, use_pallas: bool,
-                      interpret: bool, axis: Optional[str] = None,
+                      interpret: Optional[bool] = None,
+                      axis: Optional[str] = None,
                       compressed: bool = False):
     """A whole mixed round as one compiled program: the chunk half runs
     first (its scatter is traced before the decode forward, so a prompt
@@ -1505,7 +1518,7 @@ def _fused_mixed_step(cfg, pcfg, params, c_toks, c_lens, c_offs, k_arena,
     instead of the host-supplied ``d_last``.  The state arenas thread
     chunk half -> decode half the same way: a prompt finishing this
     round decodes from its own just-scattered recurrent state."""
-    c_tokens, k_arena, v_arena, conv_arena, ssm_arena = \
+    c_tokens, c_logits, k_arena, v_arena, conv_arena, ssm_arena = \
         _fused_chunk_prefill_step(
             cfg, pcfg, params, c_toks, c_lens, c_offs, k_arena, v_arena,
             c_bt, c_plens, c_pages, c_slots, c_src, c_seed, c_temps,
@@ -1519,7 +1532,8 @@ def _fused_mixed_step(cfg, pcfg, params, c_toks, c_lens, c_offs, k_arena,
         d_pages, d_slots, d_seed, d_temps, conv_arena, ssm_arena, d_srows,
         use_pallas=use_pallas, interpret=interpret, axis=axis,
         compressed=compressed)
-    return c_tokens, d_tokens, k_arena, v_arena, conv_arena, ssm_arena
+    return (c_tokens, c_logits, d_tokens, k_arena, v_arena, conv_arena,
+            ssm_arena)
 
 
 # ---------------------------------------------------------------------- #
@@ -1531,7 +1545,8 @@ def _fused_prefill_step(cfg, pcfg, params, toks, lens, k_arena, v_arena,
                         pages, slots, src, seed, temps, conv_arena=None,
                         ssm_arena=None, srows=None, *,
                         has_writes: bool, use_pallas: bool,
-                        interpret: bool, axis: Optional[str] = None,
+                        interpret: Optional[bool] = None,
+                        axis: Optional[str] = None,
                         compressed: bool = False):
     """Masked prefill forward + in-jit KV scatter + first-token
     selection: a whole prefill batch as one compiled program over
@@ -1543,7 +1558,8 @@ def _fused_prefill_step(cfg, pcfg, params, toks, lens, k_arena, v_arena,
     (pad entries duplicate entry 0 — identical writes, a deterministic
     no-op).  ``has_writes=False`` (static: the all-shared-prefix batch,
     or a pure-SSM engine with no KV to write) skips the scatter
-    entirely; SSM state scatters inside the forward's scan.
+    entirely; SSM state scatters inside the forward's scan.  Returns the
+    tokens, the (B, V) logits they were chosen from, then the arenas.
     """
     logits, k_all, v_all, conv_arena, ssm_arena = _prefill_forward(
         cfg, pcfg, params, toks, lens, use_pallas=use_pallas,
@@ -1564,14 +1580,15 @@ def _fused_prefill_step(cfg, pcfg, params, toks, lens, k_arena, v_arena,
         v_arena = scatter(v_arena, v_all)
     tokens = _select_tokens(logits, temps, seed, use_pallas=use_pallas,
                             interpret=interpret)
-    return tokens, k_arena, v_arena, conv_arena, ssm_arena
+    return tokens, logits, k_arena, v_arena, conv_arena, ssm_arena
 
 
 def _fused_chunk_prefill_step(cfg, pcfg, params, toks, lens, offs, k_arena,
                               v_arena, bt, plens, pages, slots, src, seed,
                               temps, conv_arena=None, ssm_arena=None,
                               srows=None, *, has_writes: bool,
-                              use_pallas: bool, interpret: bool,
+                              use_pallas: bool,
+                              interpret: Optional[bool] = None,
                               axis: Optional[str] = None,
                               compressed: bool = False):
     """Chunk forward (prefix-KV attention over committed arena pages) +
@@ -1606,12 +1623,13 @@ def _fused_chunk_prefill_step(cfg, pcfg, params, toks, lens, offs, k_arena,
         v_arena = scatter(v_arena, v_all)
     tokens = _select_tokens(logits, temps, seed, use_pallas=use_pallas,
                             interpret=interpret)
-    return tokens, k_arena, v_arena, conv_arena, ssm_arena
+    return tokens, logits, k_arena, v_arena, conv_arena, ssm_arena
 
 
 def _chunk_prefill_forward(cfg: ModelConfig, pcfg, params, toks, lens, offs,
                            k_arena, v_arena, bt, plens, *,
-                           use_pallas: bool = False, interpret: bool = True,
+                           use_pallas: bool = False,
+                           interpret: Optional[bool] = None,
                            axis: Optional[str] = None,
                            compressed: bool = False, conv_arena=None,
                            ssm_arena=None, srows=None):
@@ -1687,7 +1705,8 @@ def _chunk_prefill_forward(cfg: ModelConfig, pcfg, params, toks, lens, offs,
 
 
 def _prefill_forward(cfg: ModelConfig, pcfg, params, toks, lens, *,
-                     use_pallas: bool = False, interpret: bool = True,
+                     use_pallas: bool = False,
+                     interpret: Optional[bool] = None,
                      axis: Optional[str] = None, compressed: bool = False,
                      conv_arena=None, ssm_arena=None, srows=None):
     """Batched prefill forward over a length-padded prompt batch:
@@ -1755,7 +1774,7 @@ def _prefill_forward(cfg: ModelConfig, pcfg, params, toks, lens, *,
 
 
 def _select_tokens(logits: jax.Array, temps: jax.Array, seed: jax.Array, *,
-                   use_pallas: bool, interpret: bool,
+                   use_pallas: bool, interpret: Optional[bool] = None,
                    rowmap: Optional[jax.Array] = None) -> jax.Array:
     """Per-request token choice: greedy rows take the argmax, sampled
     rows take a D-RaNGe inverse-CDF draw at their own temperature.  An
@@ -1829,7 +1848,7 @@ def _logits_reduce(p, x, cfg, axis=None, compressed=False, fp32=True):
     if fp32:
         out = out.astype(jnp.float32)
     vloc = table.shape[0]
-    world = _axis_size(axis)
+    world = jax.lax.axis_size(axis)
     full = jnp.zeros(out.shape[:-1] + (vloc * world,), out.dtype)
     idx = (jnp.int32(0),) * (out.ndim - 1) + (
         jax.lax.axis_index(axis).astype(jnp.int32) * vloc,)
@@ -1932,7 +1951,8 @@ def _run_kinds(cfg, pcfg, kinds, p_layer, x, sin, cos, attend, conv_l,
 
 def _paged_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
                           v_arena, block_tables, lengths, *,
-                          use_pallas: bool = False, interpret: bool = True,
+                          use_pallas: bool = False,
+                          interpret: Optional[bool] = None,
                           axis: Optional[str] = None,
                           compressed: bool = False, conv_arena=None,
                           ssm_arena=None, srows=None, alive=None):
@@ -2002,7 +2022,8 @@ def _paged_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
 
 def _eager_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
                           v_arena, block_tables, lengths, *,
-                          use_pallas: bool = False, interpret: bool = True,
+                          use_pallas: bool = False,
+                          interpret: Optional[bool] = None,
                           conv_arena=None, ssm_arena=None, srows=None):
     """Pre-fusion baseline: Python loop over layers, one jitted
     paged-attention dispatch per layer.  Shares ``_sublayer`` and the

@@ -76,18 +76,17 @@ class TestGradCompression:
             os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={world}"
             world = {world}
             import jax, jax.numpy as jnp, numpy as np
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.distributed.compression import psum_compressed
             mesh = jax.make_mesh((world,), ("data",))
             rng = np.random.default_rng(0)
             x = jnp.asarray(rng.normal(size=(world, 257)).astype(np.float32))
-            exact = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
-                              in_specs=P("data"), out_specs=P(None),
-                              check_rep=False)(x)
-            comp = shard_map(lambda v: psum_compressed(v[0], "data")[None],
-                             mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                             check_rep=False)(x)
+            exact = jax.shard_map(lambda v: jax.lax.psum(v, "data"),
+                                  mesh=mesh, in_specs=P("data"),
+                                  out_specs=P(None), check_vma=False)(x)
+            comp = jax.shard_map(
+                lambda v: psum_compressed(v[0], "data")[None], mesh=mesh,
+                in_specs=P("data"), out_specs=P("data"), check_vma=False)(x)
             err = np.abs(np.asarray(comp[0] - exact[0]))
             rel = err.max() / (np.abs(np.asarray(exact[0])).max() + 1e-9)
             assert rel < 0.02, rel
@@ -135,15 +134,14 @@ class TestGradCompression:
         pipeline: a length-257 input (pads to a second 256-block) with
         exact-zero tail must come back with that tail EXACTLY zero, and
         the non-zero part within one quantization step."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import psum_compressed
         mesh = make_local_mesh(1, 1)
         x = np.zeros(257, np.float32)
         x[:100] = np.linspace(-3, 3, 100, dtype=np.float32)
-        out = shard_map(lambda v: psum_compressed(v, "model"),
-                        mesh=mesh, in_specs=P(), out_specs=P(),
-                        check_rep=False)(jnp.asarray(x))
+        out = jax.shard_map(lambda v: psum_compressed(v, "model"),
+                            mesh=mesh, in_specs=P(), out_specs=P(),
+                            check_vma=False)(jnp.asarray(x))
         out = np.asarray(out)
         assert (out[100:] == 0.0).all()
         assert np.abs(out[:100] - x[:100]).max() <= (6 / 127) * 1.01

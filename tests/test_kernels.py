@@ -49,13 +49,13 @@ class TestRowClone:
         pages = rng.permutation(n_pages)
         src = jnp.asarray(pages[:n_copies].astype(np.int32))
         dst = jnp.asarray(pages[n_copies:2 * n_copies].astype(np.int32))
-        out = rc.page_copy(arena, src, dst, block_cols=64, interpret=True)
+        out = rc.page_copy(arena, src, dst, interpret=True)
         np.testing.assert_array_equal(out, rc_ref.page_copy(arena, src, dst))
 
     def test_page_init_matches_ref(self):
         arena = jnp.ones((8, 128), jnp.float32)
         dst = jnp.asarray([1, 5], jnp.int32)
-        out = rc.page_init(arena, dst, 0.0, block_cols=64, interpret=True)
+        out = rc.page_init(arena, dst, 0.0, interpret=True)
         np.testing.assert_array_equal(out, rc_ref.page_init(arena, dst, 0.0))
 
 
@@ -75,8 +75,7 @@ class TestRowCloneBatched:
         pages = rng.permutation(n_pages)
         src = jnp.asarray(pages[:n_copies].astype(np.int32))
         dst = jnp.asarray(pages[n_copies:2 * n_copies].astype(np.int32))
-        out = rc.page_copy_batched(arena, src, dst, block_cols=64,
-                                   interpret=True)
+        out = rc.page_copy_batched(arena, src, dst, interpret=True)
         np.testing.assert_array_equal(
             out, rc_ref.page_copy_batched(arena, src, dst))
 
@@ -87,8 +86,7 @@ class TestRowCloneBatched:
         arena = jnp.ones((layers, 12, 96), jnp.float32)
         dst = jnp.asarray(
             np.random.default_rng(n_init).permutation(12)[:n_init].astype(np.int32))
-        out = rc.page_init_batched(arena, dst, value, block_cols=64,
-                                   interpret=True)
+        out = rc.page_init_batched(arena, dst, value, interpret=True)
         np.testing.assert_allclose(
             out, rc_ref.page_init_batched(arena, dst, value), rtol=1e-6)
 
@@ -114,18 +112,17 @@ class TestRowCloneBatched:
         arena = jnp.arange(2 * 64, dtype=jnp.float32).reshape(1, 2, 64)
         out = rc.page_copy_batched(arena, jnp.asarray([0], jnp.int32),
                                    jnp.asarray([1], jnp.int32),
-                                   block_cols=64, interpret=True)
+                                   interpret=True)
         np.testing.assert_array_equal(out[0, 1], arena[0, 0])
 
     def test_non_aligned_page_elems(self):
-        # page_elems not a multiple of block_cols (or the VMEM lane width):
-        # interpret mode masks the ragged final column block
+        # page_elems not a multiple of the VMEM lane width: the page
+        # still moves as one whole block
         arena = jnp.asarray(np.random.default_rng(3).normal(
             size=(2, 6, 100)).astype(np.float32))
         src = jnp.asarray([0, 2], jnp.int32)
         dst = jnp.asarray([1, 3], jnp.int32)
-        out = rc.page_copy_batched(arena, src, dst, block_cols=64,
-                                   interpret=True)
+        out = rc.page_copy_batched(arena, src, dst, interpret=True)
         np.testing.assert_array_equal(
             out, rc_ref.page_copy_batched(arena, src, dst))
 
@@ -133,8 +130,7 @@ class TestRowCloneBatched:
         # duplicate destinations are well-defined for init (same fill)
         arena = jnp.ones((2, 8, 32), jnp.float32)
         dst = jnp.asarray([3, 3, 5], jnp.int32)
-        out = rc.page_init_batched(arena, dst, 0.0, block_cols=32,
-                                   interpret=True)
+        out = rc.page_init_batched(arena, dst, 0.0, interpret=True)
         assert float(jnp.abs(out[:, [3, 5]]).sum()) == 0.0
         assert float(jnp.abs(out[:, [0, 1, 2, 4, 6, 7]] - 1.0).sum()) == 0.0
 

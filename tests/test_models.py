@@ -246,3 +246,24 @@ class TestMoE:
             out, aux = M.moe_layer(r, ParallelConfig(moe_impl="shard_map"), p, x)
         assert bool(jnp.isfinite(out).all())
         assert float(aux) > 0.5  # load-balance loss in a sane range
+
+
+@pytest.mark.parametrize("path,expect", [
+    (("group0", "0_attn", "attn", "wq"), "d_model"),
+    (("group0", "0_attn", "attn", "wk"), "d_model"),
+    (("group0", "0_attn", "attn", "wo"), "heads*head_dim"),
+    (("group0", "1_mlp", "mlp", "down"), "d_ff"),
+])
+def test_fan_in_is_the_summed_dims(path, expect):
+    """The init scales each projection by the inputs it sums over: the
+    model width for q/k/v (not the head count), heads x head_dim for the
+    output projection — so attention scores start O(1), not hard-max."""
+    from repro.models.params import fan_in, is_def
+    cfg = ARCHS["granite-3-8b"]
+    d = T.model_defs(cfg)
+    for k in path:
+        d = d[k]
+    assert is_def(d)
+    want = {"d_model": cfg.d_model, "d_ff": cfg.d_ff,
+            "heads*head_dim": cfg.num_heads * cfg.resolved_head_dim}[expect]
+    assert fan_in(d) == want
