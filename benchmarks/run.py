@@ -6,14 +6,13 @@ import traceback
 
 
 def main() -> None:
-    from benchmarks import kernels, paper_tables, roofline_report, serving_e2e
+    from benchmarks import kernels, paper_tables, serving_e2e
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     sections = [
         ("paper_tables (RowClone + D-RaNGe reproduction)", paper_tables.main),
         ("kernels", kernels.main),
         ("serving_e2e", serving_e2e.main),
-        ("roofline_report (from dry-run artifacts)", roofline_report.main),
     ]
     failed = []
     for name, fn in sections:

@@ -196,6 +196,12 @@ from repro.models.layers import (rmsnorm, cast, logits_out, embed, mlp,
                                  apply_rope, rope_sincos)
 from .kv_cache import PagedKVCache, _bucket_pow2
 
+# Host spans of the serving round (``engine.round`` and its phases), on
+# the profiler's clock beside the device's ops; with no profiler running
+# each costs only the annotation's enter/exit.  Host code only: never
+# inside a traced function.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclass
 class Request:
@@ -381,7 +387,13 @@ class PagedEngine:
                       "prefix_hits": 0, "prefix_hit_tokens": 0,
                       "prefix_evictions": 0,
                       "state_pages": 0, "state_forks": 0,
-                      "prefix_declined_ssm": 0}
+                      "prefix_declined_ssm": 0,
+                      # useful over attempted work of the padded launches:
+                      # arena tokens the decode rows attend over against
+                      # the rows x table width x page size gathered, and
+                      # real chunk tokens against rows x chunk bucket
+                      "decode_kv_tokens": 0, "decode_kv_slots": 0,
+                      "chunk_tokens": 0, "chunk_token_slots": 0}
         self._step = self._build_fused_step() if fused else None
         self._prefill_step = (self._build_fused_prefill_step()
                               if fused_prefill else None)
@@ -461,66 +473,76 @@ class PagedEngine:
         engine burns up to ``decode_block_rounds`` rounds per dispatch
         in the persistent K-block loop — ``rounds`` advances by the
         rounds the block actually consumed, so ``max_rounds`` keeps its
-        round-at-a-time meaning."""
+        round-at-a-time meaning.  Each iteration is one ``engine.round``
+        profiler span, so ``step()`` opens exactly one."""
         results: Dict[int, List[int]] = {}
         rounds = 0
-        chunked = self.fused_prefill and self.max_prefill_chunk is not None
         while ((self.queue or self._chunk_q or self.active)
                and rounds < max_rounds):
-            had_active = bool(self.active)
-            decoded = False
-            if self.queue or self._chunk_q:
-                if self.active:
-                    # overlap the pre-round CoW flush with prefill work:
-                    # reserve the decode tails NOW and dispatch the
-                    # coalesced copies, so forking workloads pay the
-                    # flush behind prefill host work (JAX dispatch is
-                    # async), not in front of the decode step
-                    self._reserve_tails(sorted(self.active))
-                    self.cache.queue.flush_overlapped(self.cache.lib.flush)
-                if chunked:
-                    prefill_toks, decoded = self._prefill_tick(
-                        allow_mixed=self._mixed_step is not None)
-                else:
-                    prefill_toks = self._prefill_round()
-                if (had_active and self.max_prefill_chunk is not None
-                        and prefill_toks > self.max_prefill_chunk):
-                    # an un-chunked prefill blew the per-round budget
-                    # while decodes were in flight: they waited behind
-                    # it — the latency chunking bounds.  Never
-                    # increments when the chunked scheduler is on.
-                    self.stats["decode_stall_rounds"] += 1
-                # a budget of 1 is satisfied by the prefill token alone:
-                # retire those now instead of decoding a surplus token
-                self._finish_done(results)
-            elif self.active and self._block_step is not None:
-                # pure decode, nothing to admit: one dispatch covers up
-                # to K rounds (never past the caller's round budget)
-                rounds += self._decode_block(max_rounds - rounds)
-                self._finish_done(results)
-                continue
-            if not decoded:
-                self._decode_round()
-            rounds += 1
-            self._finish_done(results)
+            with _span("engine.round"):
+                rounds += self._round(results, max_rounds - rounds)
         return results
 
+    def _round(self, results: Dict[int, List[int]], budget: int) -> int:
+        """One iteration of ``run``; returns the rounds it consumed."""
+        chunked = self.fused_prefill and self.max_prefill_chunk is not None
+        had_active = bool(self.active)
+        decoded = False
+        if self.queue or self._chunk_q:
+            if self.active:
+                # overlap the pre-round CoW flush with prefill work:
+                # reserve the decode tails NOW and dispatch the
+                # coalesced copies, so forking workloads pay the
+                # flush behind prefill host work (JAX dispatch is
+                # async), not in front of the decode step
+                with _span("engine.plan"):
+                    self._reserve_tails(sorted(self.active))
+                with _span("engine.flush"):
+                    self.cache.queue.flush_overlapped(self.cache.lib.flush)
+            if chunked:
+                prefill_toks, decoded = self._prefill_tick(
+                    allow_mixed=self._mixed_step is not None)
+            else:
+                prefill_toks = self._prefill_round()
+            if (had_active and self.max_prefill_chunk is not None
+                    and prefill_toks > self.max_prefill_chunk):
+                # an un-chunked prefill blew the per-round budget
+                # while decodes were in flight: they waited behind
+                # it — the latency chunking bounds.  Never
+                # increments when the chunked scheduler is on.
+                self.stats["decode_stall_rounds"] += 1
+            # a budget of 1 is satisfied by the prefill token alone:
+            # retire those now instead of decoding a surplus token
+            self._finish_done(results)
+        elif self.active and self._block_step is not None:
+            # pure decode, nothing to admit: one dispatch covers up
+            # to K rounds (never past the caller's round budget)
+            n = self._decode_block(budget)
+            self._finish_done(results)
+            return n
+        if not decoded:
+            self._decode_round()
+        self._finish_done(results)
+        return 1
+
     def _finish_done(self, results: Dict[int, List[int]]) -> None:
-        # mirror the cache's prefix-sharing counters (engine.stats is
-        # the one stats surface servers/benches read)
-        for key in ("prefix_hits", "prefix_hit_tokens", "prefix_evictions",
-                    "state_pages", "state_forks", "prefix_declined_ssm"):
-            self.stats[key] = self.cache.stats[key]
-        for rid in list(self.active):
-            r = self.active[rid]
-            hit_eos = (r.eos_token_id is not None and r.out_tokens
-                       and r.out_tokens[-1] == r.eos_token_id)
-            if len(r.out_tokens) >= r.max_new_tokens or hit_eos:
-                r.done = True
-                results[rid] = r.out_tokens
-                self.cache.free(rid)
-                del self.active[rid]
-                self._reserved_tails.discard(rid)
+        with _span("engine.commit"):
+            # mirror the cache's prefix-sharing counters (engine.stats
+            # is the one stats surface servers/benches read)
+            for key in ("prefix_hits", "prefix_hit_tokens",
+                        "prefix_evictions", "state_pages", "state_forks",
+                        "prefix_declined_ssm"):
+                self.stats[key] = self.cache.stats[key]
+            for rid in list(self.active):
+                r = self.active[rid]
+                hit_eos = (r.eos_token_id is not None and r.out_tokens
+                           and r.out_tokens[-1] == r.eos_token_id)
+                if len(r.out_tokens) >= r.max_new_tokens or hit_eos:
+                    r.done = True
+                    results[rid] = r.out_tokens
+                    self.cache.free(rid)
+                    del self.active[rid]
+                    self._reserved_tails.discard(rid)
 
     # --------------------------- internals ----------------------------- #
 
@@ -572,8 +594,9 @@ class PagedEngine:
         """
         eng = self
 
-        def step(params, last, k_arena, v_arena, bt, lens, pages, slots,
-                 seed, temps, conv_arena, ssm_arena, srows):
+        # named so its device module reads jit_decode_step in a profile
+        def decode_step(params, last, k_arena, v_arena, bt, lens, pages,
+                        slots, seed, temps, conv_arena, ssm_arena, srows):
             eng.stats["jit_traces"] += 1
             fn = functools.partial(_fused_decode_step, eng.cfg, eng.pcfg,
                                    **eng._step_kwargs())
@@ -585,7 +608,7 @@ class PagedEngine:
 
         donate = ((2, 3, 10, 11) if jax.default_backend() in ("tpu", "gpu")
                   else ())
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(decode_step, donate_argnums=donate)
 
     def _build_fused_prefill_step(self):
         """One jit covering the whole prefill batch: masked forward +
@@ -595,8 +618,9 @@ class PagedEngine:
         a trace-cache miss)."""
         eng = self
 
-        def step(params, toks, lens, k_arena, v_arena, pages, slots, src,
-                 seed, temps, conv_arena, ssm_arena, srows, has_writes):
+        def prefill_step(params, toks, lens, k_arena, v_arena, pages, slots,
+                         src, seed, temps, conv_arena, ssm_arena, srows,
+                         has_writes):
             eng.stats["prefill_jit_traces"] += 1
             fn = functools.partial(_fused_prefill_step, eng.cfg, eng.pcfg,
                                    has_writes=has_writes,
@@ -609,7 +633,7 @@ class PagedEngine:
 
         donate = ((3, 4, 10, 11) if jax.default_backend() in ("tpu", "gpu")
                   else ())
-        return jax.jit(step, donate_argnums=donate,
+        return jax.jit(prefill_step, donate_argnums=donate,
                        static_argnames=("has_writes",))
 
     def _build_fused_chunk_step(self):
@@ -620,9 +644,9 @@ class PagedEngine:
         monolithic prefill (the body only runs on a trace-cache miss)."""
         eng = self
 
-        def step(params, toks, lens, offs, k_arena, v_arena, bt, plens,
-                 pages, slots, src, seed, temps, conv_arena, ssm_arena,
-                 srows, has_writes):
+        def chunk_step(params, toks, lens, offs, k_arena, v_arena, bt,
+                       plens, pages, slots, src, seed, temps, conv_arena,
+                       ssm_arena, srows, has_writes):
             eng.stats["prefill_jit_traces"] += 1
             fn = functools.partial(_fused_chunk_prefill_step, eng.cfg,
                                    eng.pcfg, has_writes=has_writes,
@@ -635,7 +659,7 @@ class PagedEngine:
 
         donate = ((4, 5, 13, 14) if jax.default_backend() in ("tpu", "gpu")
                   else ())
-        return jax.jit(step, donate_argnums=donate,
+        return jax.jit(chunk_step, donate_argnums=donate,
                        static_argnames=("has_writes",))
 
     def _build_fused_block_step(self):
@@ -647,9 +671,9 @@ class PagedEngine:
         counter bump is exactly a retrace counter."""
         eng = self
 
-        def step(params, last, steps, k_arena, v_arena, bt, lens, pages,
-                 slots, eos, seed, temps, rowmap, conv_arena, ssm_arena,
-                 srows):
+        def block_step(params, last, steps, k_arena, v_arena, bt, lens,
+                       pages, slots, eos, seed, temps, rowmap, conv_arena,
+                       ssm_arena, srows):
             eng.stats["block_jit_traces"] += 1
             fn = functools.partial(_fused_block_step, eng.cfg, eng.pcfg,
                                    **eng._step_kwargs())
@@ -661,7 +685,7 @@ class PagedEngine:
 
         donate = ((3, 4, 13, 14) if jax.default_backend() in ("tpu", "gpu")
                   else ())
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(block_step, donate_argnums=donate)
 
     def _build_fused_mixed_step(self):
         """One jit covering a whole mixed round: chunk batch forward +
@@ -673,11 +697,11 @@ class PagedEngine:
         comparable oracles."""
         eng = self
 
-        def step(params, c_toks, c_lens, c_offs, k_arena, v_arena, c_bt,
-                 c_plens, c_pages, c_slots, c_src, c_seed, c_temps,
-                 d_last, d_bt, d_lens, d_pages, d_slots, d_seed, d_temps,
-                 d_from_chunk, conv_arena, ssm_arena, c_srows, d_srows,
-                 has_writes):
+        def mixed_step(params, c_toks, c_lens, c_offs, k_arena, v_arena,
+                       c_bt, c_plens, c_pages, c_slots, c_src, c_seed,
+                       c_temps, d_last, d_bt, d_lens, d_pages, d_slots,
+                       d_seed, d_temps, d_from_chunk, conv_arena, ssm_arena,
+                       c_srows, d_srows, has_writes):
             eng.stats["mixed_jit_traces"] += 1
             fn = functools.partial(_fused_mixed_step, eng.cfg, eng.pcfg,
                                    has_writes=has_writes,
@@ -692,7 +716,7 @@ class PagedEngine:
 
         donate = ((4, 5, 21, 22) if jax.default_backend() in ("tpu", "gpu")
                   else ())
-        return jax.jit(step, donate_argnums=donate,
+        return jax.jit(mixed_step, donate_argnums=donate,
                        static_argnames=("has_writes",))
 
     def _prefill_round(self) -> int:
@@ -714,11 +738,12 @@ class PagedEngine:
         # lets the radix prefix cache longest-prefix-match each prompt
         # against every previously COMMITTED prompt (a batch submitted
         # together can't hit on itself — inserts happen at commit)
-        for r in reqs:
-            self.cache.create(r.req_id, len(r.prompt),
-                              share_with=r.share_with,
-                              shared_len=r.shared_len,
-                              tokens=r.prompt)
+        with _span("engine.admit"):
+            for r in reqs:
+                self.cache.create(r.req_id, len(r.prompt),
+                                  share_with=r.share_with,
+                                  shared_len=r.shared_len,
+                                  tokens=r.prompt)
         groups: Dict[int, List[Request]] = {}
         for r in reqs:
             groups.setdefault(_bucket_pow2(len(r.prompt)), []).append(r)
@@ -744,7 +769,8 @@ class PagedEngine:
         Returns ``(prompt_tokens_processed, decoded)`` — ``decoded``
         tells the caller this round's decode already ran."""
         self._admit_queue()
-        batch, sc = self._select_chunk_batch()
+        with _span("engine.plan"):
+            batch, sc = self._select_chunk_batch()
         if not batch:
             return 0, False
         toks = sum(clen for _, clen in batch)
@@ -812,20 +838,22 @@ class PagedEngine:
         chunk budget (a whole-prompt forward here would reintroduce the
         decode stall this scheduler exists to remove)."""
         reqs, self.queue = self.queue, []
-        for r in reqs:
-            seq = self.cache.create(r.req_id, len(r.prompt),
-                                    share_with=r.share_with,
-                                    shared_len=r.shared_len,
-                                    tokens=r.prompt)
-            off = seq.shared_prefix_pages * self.cache.page_size
-            n = len(r.prompt)
-            if off >= n:
-                st = _ChunkPrefill(r, n - 1, dep=r.share_with, dep_len=n,
-                                   write=False)
-            else:
-                st = _ChunkPrefill(r, off, dep=r.share_with, dep_len=off)
-            self._chunk_q.append(st)
-            self._chunk_by_id[r.req_id] = st
+        with _span("engine.admit"):
+            for r in reqs:
+                seq = self.cache.create(r.req_id, len(r.prompt),
+                                        share_with=r.share_with,
+                                        shared_len=r.shared_len,
+                                        tokens=r.prompt)
+                off = seq.shared_prefix_pages * self.cache.page_size
+                n = len(r.prompt)
+                if off >= n:
+                    st = _ChunkPrefill(r, n - 1, dep=r.share_with,
+                                       dep_len=n, write=False)
+                else:
+                    st = _ChunkPrefill(r, off, dep=r.share_with,
+                                       dep_len=off)
+                self._chunk_q.append(st)
+                self._chunk_by_id[r.req_id] = st
 
     def _chunk_operands(self, batch: List[tuple], sc: int) -> dict:
         """Assemble a chunk batch's device operands + scatter plan
@@ -894,20 +922,22 @@ class PagedEngine:
         join the active set.  Returns the still-unfinished states."""
         toks_np = None
         unfinished: List[_ChunkPrefill] = []
-        for i, (st, clen) in enumerate(batch):
-            st.off += clen
-            if st.remaining <= 0:
-                if toks_np is None:         # the batch's one host transfer
-                    toks_np = np.asarray(tokens)
-                st.req.out_tokens.append(int(toks_np[i]))
-                _keep_logits(st.req, logits, i)
-                self.active[st.req.req_id] = st.req
-                self.stats["prefills"] += 1
-                del self._chunk_by_id[st.req.req_id]
-                # the prompt's full pages now hold real KV: index them
-                self.cache.commit_prefix(st.req.req_id, st.req.prompt)
-            else:
-                unfinished.append(st)
+        with _span("engine.commit"):
+            for i, (st, clen) in enumerate(batch):
+                st.off += clen
+                if st.remaining <= 0:
+                    if toks_np is None:     # the batch's one host transfer
+                        with _span("engine.sync"):
+                            toks_np = np.asarray(tokens)
+                    st.req.out_tokens.append(int(toks_np[i]))
+                    _keep_logits(st.req, logits, i)
+                    self.active[st.req.req_id] = st.req
+                    self.stats["prefills"] += 1
+                    del self._chunk_by_id[st.req.req_id]
+                    # the prompt's full pages now hold real KV: index them
+                    self.cache.commit_prefix(st.req.req_id, st.req.prompt)
+                else:
+                    unfinished.append(st)
         return unfinished
 
     def _prefill_chunk_batch_fused(self, batch: List[tuple],
@@ -921,28 +951,34 @@ class PagedEngine:
         the prompt.  Returns the still-unfinished chunk states."""
         # the step READS the arena (prefix gather): any pending backlog
         # must land first
-        self.cache.flush_pending()
-        c = self._chunk_operands(batch, sc)
-        srows, conv, ssm = self._state_operands(c["rids"])
-        self.rng_ctr += 1
-        seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        tokens, logits, k_arena, v_arena, conv_a, ssm_a = self._chunk_step(
-            self.params, c["toks"], c["lens"], c["offs"],
-            self.cache.k_arena, self.cache.v_arena, c["bt"], c["plens"],
-            c["pages"], c["slots"], c["src"], seed, c["temps"],
-            conv, ssm, srows,
-            has_writes=c["n_valid"] > 0 and self._has_attn)
-        # chunk scatters account as the fused_prefill kind, same as the
-        # monolithic batch (PimOpQueue.launches_by_kind, trace kv_writes)
-        kv_plan = (c["plan_pages"], c["plan_slots"]) if self._has_attn \
-            else ([], [])
-        self.cache.commit_fused_prefill(k_arena, v_arena, *kv_plan)
-        if self._has_ssm:
-            self.cache.state.adopt(conv_a, ssm_a)
-            self.cache.state.record_fused_write(
-                [st.req.req_id for st, _ in batch])
-        self.stats["prefill_chunks"] += len(batch)
-        self.stats["fused_prefill_dispatches"] += 1
+        with _span("engine.flush"):
+            self.cache.flush_pending()
+        with _span("engine.plan"):
+            c = self._chunk_operands(batch, sc)
+            srows, conv, ssm = self._state_operands(c["rids"])
+            self.rng_ctr += 1
+            seed = self.rng_seed + jnp.uint32(self.rng_ctr)
+        with _span("engine.dispatch.chunk"):
+            tokens, logits, k_arena, v_arena, conv_a, ssm_a = \
+                self._chunk_step(
+                    self.params, c["toks"], c["lens"], c["offs"],
+                    self.cache.k_arena, self.cache.v_arena, c["bt"],
+                    c["plens"], c["pages"], c["slots"], c["src"], seed,
+                    c["temps"], conv, ssm, srows,
+                    has_writes=c["n_valid"] > 0 and self._has_attn)
+        with _span("engine.commit"):
+            # chunk scatters account as the fused_prefill kind, same as
+            # the monolithic batch (PimOpQueue.launches_by_kind, trace
+            # kv_writes)
+            kv_plan = (c["plan_pages"], c["plan_slots"]) \
+                if self._has_attn else ([], [])
+            self.cache.commit_fused_prefill(k_arena, v_arena, *kv_plan)
+            if self._has_ssm:
+                self.cache.state.adopt(conv_a, ssm_a)
+                self.cache.state.record_fused_write(
+                    [st.req.req_id for st, _ in batch])
+            self._note_chunk(batch, c)
+            self.stats["fused_prefill_dispatches"] += 1
         return self._finish_chunks(batch, tokens, logits)
 
     def _mixed_round(self, batch: List[tuple], sc: int,
@@ -970,80 +1006,136 @@ class PagedEngine:
         # reserve every decode row's tail BEFORE planning the chunk
         # scatter: a CoW retarget must be seen by the plan, and the
         # coalesced copies must land before the step reads the arena
-        self._reserve_tails(d_rids)
-        self._reserved_tails.clear()
-        self.cache.flush_pending()
-        c = self._chunk_operands(batch, sc)
-        row_of = {st.req.req_id: i for i, (st, _) in enumerate(batch)}
-        B = len(d_rids)
-        Bp = _bucket_pow2(B)
-        idx = list(range(B)) + [0] * (Bp - B)   # pad rows duplicate row 0
-        seqs = [self.cache.seqs[d_rids[i]] for i in idx]
-        d_last = np.zeros((Bp,), np.int32)
-        d_from = np.full((Bp,), -1, np.int32)
-        d_temps = np.zeros((Bp,), np.float32)
-        for row, i in enumerate(idx):
-            rid = d_rids[i]
-            r = reqmap[rid]
-            d_temps[row] = r.temperature
-            if rid in fin:               # token arrives in-jit
-                d_from[row] = row_of[rid]
-            else:
-                d_last[row] = r.out_tokens[-1]
-        d_pages = np.asarray([s.pages[-1] for s in seqs], np.int32)
-        d_slots = np.asarray([s.length % self.cache.page_size
-                              for s in seqs], np.int32)
-        d_bt, d_lens = self.cache.block_table([d_rids[i] for i in idx])
-        c_srows, conv, ssm = self._state_operands(c["rids"])
-        d_srows, _, _ = self._state_operands([d_rids[i] for i in idx])
-        self.rng_ctr += 1
-        c_seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        self.rng_ctr += 1
-        d_seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        c_tokens, c_logits, d_tokens, k_arena, v_arena, conv_a, ssm_a = \
-            self._mixed_step(
+        with _span("engine.plan"):
+            self._reserve_tails(d_rids)
+            self._reserved_tails.clear()
+        with _span("engine.flush"):
+            self.cache.flush_pending()
+        with _span("engine.plan"):
+            c = self._chunk_operands(batch, sc)
+            row_of = {st.req.req_id: i for i, (st, _) in enumerate(batch)}
+            B = len(d_rids)
+            Bp = _bucket_pow2(B)
+            idx = list(range(B)) + [0] * (Bp - B)   # pad rows duplicate row 0
+            seqs = [self.cache.seqs[d_rids[i]] for i in idx]
+            d_last = np.zeros((Bp,), np.int32)
+            d_from = np.full((Bp,), -1, np.int32)
+            d_temps = np.zeros((Bp,), np.float32)
+            for row, i in enumerate(idx):
+                rid = d_rids[i]
+                r = reqmap[rid]
+                d_temps[row] = r.temperature
+                if rid in fin:               # token arrives in-jit
+                    d_from[row] = row_of[rid]
+                else:
+                    d_last[row] = r.out_tokens[-1]
+            d_pages = np.asarray([s.pages[-1] for s in seqs], np.int32)
+            d_slots = np.asarray([s.length % self.cache.page_size
+                                  for s in seqs], np.int32)
+            d_bt, d_lens = self.cache.block_table([d_rids[i] for i in idx])
+            self._note_decode_kv(d_rids, Bp, d_bt.shape[1])
+            c_srows, conv, ssm = self._state_operands(c["rids"])
+            d_srows, _, _ = self._state_operands([d_rids[i] for i in idx])
+            self.rng_ctr += 1
+            c_seed = self.rng_seed + jnp.uint32(self.rng_ctr)
+            self.rng_ctr += 1
+            d_seed = self.rng_seed + jnp.uint32(self.rng_ctr)
+            d_last, d_pages, d_slots, d_temps, d_from = (
+                jnp.asarray(x) for x in (d_last, d_pages, d_slots, d_temps,
+                                         d_from))
+        with _span("engine.dispatch.mixed"):
+            (c_tokens, c_logits, d_tokens, k_arena, v_arena, conv_a,
+             ssm_a) = self._mixed_step(
                 self.params, c["toks"], c["lens"], c["offs"],
                 self.cache.k_arena, self.cache.v_arena, c["bt"],
                 c["plens"], c["pages"], c["slots"], c["src"], c_seed,
-                c["temps"], jnp.asarray(d_last), d_bt, d_lens,
-                jnp.asarray(d_pages), jnp.asarray(d_slots), d_seed,
-                jnp.asarray(d_temps), jnp.asarray(d_from), conv, ssm,
-                c_srows, d_srows,
+                c["temps"], d_last, d_bt, d_lens, d_pages, d_slots, d_seed,
+                d_temps, d_from, conv, ssm, c_srows, d_srows,
                 has_writes=c["n_valid"] > 0 and self._has_attn)
-        kv_plan = (c["plan_pages"], c["plan_slots"]) if self._has_attn \
-            else ([], [])
-        self.cache.commit_fused_prefill(k_arena, v_arena, *kv_plan,
-                                        kind=None)
-        self.cache.commit_fused_round(d_rids, k_arena, v_arena, kind=None,
-                                      wrote_kv=self._has_attn)
-        if self._has_ssm:
-            self.cache.state.adopt(conv_a, ssm_a)
-            # trace both halves' state writes: the chunk rows' prefill
-            # state and the decode rows' round state (one fused launch)
-            self.cache.state.record_fused_write(
-                [st.req.req_id for st, _ in batch])
-            self.cache.state.record_fused_write(d_rids)
-        # the whole round — chunk scatter included — was ONE launch
-        self.cache.queue.count_external("fused_mixed")
-        self.stats["prefill_chunks"] += len(batch)
-        self.stats["mixed_dispatches"] += 1
+        with _span("engine.commit"):
+            kv_plan = (c["plan_pages"], c["plan_slots"]) \
+                if self._has_attn else ([], [])
+            self.cache.commit_fused_prefill(k_arena, v_arena, *kv_plan,
+                                            kind=None)
+            self.cache.commit_fused_round(d_rids, k_arena, v_arena,
+                                          kind=None, wrote_kv=self._has_attn)
+            if self._has_ssm:
+                self.cache.state.adopt(conv_a, ssm_a)
+                # trace both halves' state writes: the chunk rows'
+                # prefill state and the decode rows' round state (one
+                # fused launch)
+                self.cache.state.record_fused_write(
+                    [st.req.req_id for st, _ in batch])
+                self.cache.state.record_fused_write(d_rids)
+            # the whole round — chunk scatter included — was ONE launch
+            self.cache.queue.count_external("fused_mixed")
+            self._note_chunk(batch, c)
+            self.stats["mixed_dispatches"] += 1
         unfinished = self._finish_chunks(batch, c_tokens, c_logits)
-        d_toks = np.asarray(d_tokens)[:B]
-        emitted = 0
-        for i, rid in enumerate(d_rids):
-            r = reqmap[rid]
-            if (rid in fin and r.eos_token_id is not None
-                    and r.out_tokens[-1] == r.eos_token_id):
-                continue       # first token was EOS: decode token is dead
-            r.out_tokens.append(int(d_toks[i]))
-            emitted += 1
-        self.stats["decode_rounds"] += 1
-        self.stats["tokens_out"] += emitted
+        with _span("engine.sync"):
+            d_toks = np.asarray(d_tokens)[:B]
+        with _span("engine.commit"):
+            emitted = 0
+            for i, rid in enumerate(d_rids):
+                r = reqmap[rid]
+                if (rid in fin and r.eos_token_id is not None
+                        and r.out_tokens[-1] == r.eos_token_id):
+                    continue   # first token was EOS: decode token is dead
+                r.out_tokens.append(int(d_toks[i]))
+                emitted += 1
+            self.stats["decode_rounds"] += 1
+            self.stats["tokens_out"] += emitted
         return unfinished
 
     def _prefill_batch_fused(self, reqs: List[Request], sp: int) -> None:
         """One compiled dispatch for a same-length-bucket prefill batch;
         one host transfer (the batch's first tokens)."""
+        B = len(reqs)
+        with _span("engine.plan"):
+            toks, lens, temps, pages, slots, src, plan = \
+                self._prefill_operands(reqs, sp)
+        # the step reads the arena (shared-prefix gathers) — any backlog
+        # (e.g. prefix-cache eviction inits from create-time pressure)
+        # must land first
+        with _span("engine.flush"):
+            self.cache.flush_pending()
+        with _span("engine.plan"):
+            idx = list(range(B)) + [0] * (toks.shape[0] - B)
+            srows, conv, ssm = self._state_operands(
+                [reqs[i].req_id for i in idx])
+            self.rng_ctr += 1
+            seed = self.rng_seed + jnp.uint32(self.rng_ctr)
+            toks, lens, temps = (jnp.asarray(x) for x in (toks, lens, temps))
+        with _span("engine.dispatch.prefill"):
+            tokens, logits, k_arena, v_arena, conv_a, ssm_a = \
+                self._prefill_step(
+                    self.params, toks, lens, self.cache.k_arena,
+                    self.cache.v_arena, pages, slots, src, seed, temps,
+                    conv, ssm, srows,
+                    has_writes=bool(plan[0]) and self._has_attn)
+        with _span("engine.commit"):
+            kv_plan = plan if self._has_attn else ([], [])
+            self.cache.commit_fused_prefill(k_arena, v_arena, *kv_plan)
+            if self._has_ssm:
+                self.cache.state.adopt(conv_a, ssm_a)
+                self.cache.state.record_fused_write(
+                    [r.req_id for r in reqs])
+        with _span("engine.sync"):
+            toks_np = np.asarray(tokens)[:B]   # the batch's one transfer
+        with _span("engine.commit"):
+            for i, r in enumerate(reqs):
+                r.out_tokens.append(int(toks_np[i]))
+                _keep_logits(r, logits, i)
+                self.active[r.req_id] = r
+                self.stats["prefills"] += 1
+                self.cache.commit_prefix(r.req_id, r.prompt)
+            self.stats["fused_prefill_dispatches"] += 1
+
+    def _prefill_operands(self, reqs: List[Request], sp: int):
+        """A prefill batch's host operands: padded tokens, lengths and
+        temperatures (numpy), the scatter plan padded to ``Bp * sp``
+        entries (``pages``, ``slots``, ``src`` on the device), and the
+        plan's valid ``(pages, slots)``."""
         B = len(reqs)
         Bp = _bucket_pow2(B)
         idx = list(range(B)) + [0] * (Bp - B)   # pad rows duplicate req 0
@@ -1084,34 +1176,9 @@ class PagedEngine:
             pages = [0] * N
             slots = [0] * N
             src = [0] * N
-        # the step reads the arena (shared-prefix gathers) — any backlog
-        # (e.g. prefix-cache eviction inits from create-time pressure)
-        # must land first
-        self.cache.flush_pending()
-        srows, conv, ssm = self._state_operands(
-            [reqs[i].req_id for i in idx])
-        self.rng_ctr += 1
-        seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        tokens, logits, k_arena, v_arena, conv_a, ssm_a = self._prefill_step(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
-            self.cache.k_arena, self.cache.v_arena,
-            jnp.asarray(pages, jnp.int32), jnp.asarray(slots, jnp.int32),
-            jnp.asarray(src, jnp.int32), seed, jnp.asarray(temps),
-            conv, ssm, srows, has_writes=n_valid > 0 and self._has_attn)
-        kv_plan = (pages[:n_valid], slots[:n_valid]) if self._has_attn \
-            else ([], [])
-        self.cache.commit_fused_prefill(k_arena, v_arena, *kv_plan)
-        if self._has_ssm:
-            self.cache.state.adopt(conv_a, ssm_a)
-            self.cache.state.record_fused_write([r.req_id for r in reqs])
-        toks_np = np.asarray(tokens)[:B]    # the batch's one host transfer
-        for i, r in enumerate(reqs):
-            r.out_tokens.append(int(toks_np[i]))
-            _keep_logits(r, logits, i)
-            self.active[r.req_id] = r
-            self.stats["prefills"] += 1
-            self.cache.commit_prefix(r.req_id, r.prompt)
-        self.stats["fused_prefill_dispatches"] += 1
+        return (toks, lens, temps, jnp.asarray(pages, jnp.int32),
+                jnp.asarray(slots, jnp.int32), jnp.asarray(src, jnp.int32),
+                (pages[:n_valid], slots[:n_valid]))
 
     def _prefill(self, req: Request) -> None:
         """Eager per-request prefill — the fused path's parity oracle:
@@ -1182,52 +1249,77 @@ class PagedEngine:
         # reserve the slot for the incoming token on every sequence; the
         # CoW copies all land in ONE batched launch before attention reads
         # the arena (constant dispatch count, however many sequences fork)
-        self._reserve_tails(rids)
-        self._reserved_tails.clear()
-        self.cache.flush_pending()
+        with _span("engine.plan"):
+            self._reserve_tails(rids)
+            self._reserved_tails.clear()
+        with _span("engine.flush"):
+            self.cache.flush_pending()
         if self.fused:
             toks = self._decode_round_fused(rids)
         else:
             toks = self._decode_round_eager(rids)
-        for i, r in enumerate(rids):
-            self.active[r].out_tokens.append(int(toks[i]))
-        self.stats["decode_rounds"] += 1
-        self.stats["tokens_out"] += len(rids)
+        with _span("engine.commit"):
+            for i, r in enumerate(rids):
+                self.active[r].out_tokens.append(int(toks[i]))
+            self.stats["decode_rounds"] += 1
+            self.stats["tokens_out"] += len(rids)
 
     def _decode_round_fused(self, rids: List[int]) -> np.ndarray:
         """One compiled dispatch for the whole round; one host transfer."""
-        B = len(rids)
-        Bp = _bucket_pow2(B)
-        # batch bucketing: pad rows duplicate sequence 0 — the duplicate
-        # attention is wasted compute, and the duplicate scatter writes
-        # the *same* values to the *same* (page, slot), so it is a no-op
-        idx = list(range(B)) + [0] * (Bp - B)
-        seqs = [self.cache.seqs[rids[i]] for i in idx]
-        last = np.asarray([[self.active[rids[i]].out_tokens[-1]]
-                           for i in idx], np.int32)
-        temps = np.asarray([self.active[rids[i]].temperature for i in idx],
-                           np.float32)
-        pages = np.asarray([s.pages[-1] for s in seqs], np.int32)
-        slots = np.asarray([s.length % self.cache.page_size for s in seqs],
-                           np.int32)
-        bt, lens = self.cache.block_table([rids[i] for i in idx])
-        srows, conv, ssm = self._state_operands([rids[i] for i in idx])
-        self.rng_ctr += 1
-        seed = self.rng_seed + jnp.uint32(self.rng_ctr)
-        tokens, k_arena, v_arena, conv_a, ssm_a = self._step(
-            self.params, jnp.asarray(last), self.cache.k_arena,
-            self.cache.v_arena, bt, lens, jnp.asarray(pages),
-            jnp.asarray(slots), seed, jnp.asarray(temps), conv, ssm,
-            srows)
-        self.cache.commit_fused_round(rids, k_arena, v_arena,
-                                      wrote_kv=self._has_attn)
-        if self._has_ssm:
-            self.cache.state.adopt(conv_a, ssm_a)
-            self.cache.state.record_fused_write(rids)
-        # per-engine count: the queue's fused_decode counter is global
-        # to the (possibly shared) lib, this one is this engine's own
-        self.stats["fused_dispatches"] += 1
-        return np.asarray(tokens)[:B]      # the round's one host transfer
+        with _span("engine.plan"):
+            B = len(rids)
+            Bp = _bucket_pow2(B)
+            # batch bucketing: pad rows duplicate sequence 0 — the
+            # duplicate attention is wasted compute, and the duplicate
+            # scatter writes the *same* values to the *same* (page,
+            # slot), so it is a no-op
+            idx = list(range(B)) + [0] * (Bp - B)
+            seqs = [self.cache.seqs[rids[i]] for i in idx]
+            last = np.asarray([[self.active[rids[i]].out_tokens[-1]]
+                               for i in idx], np.int32)
+            temps = np.asarray([self.active[rids[i]].temperature
+                                for i in idx], np.float32)
+            pages = np.asarray([s.pages[-1] for s in seqs], np.int32)
+            slots = np.asarray([s.length % self.cache.page_size
+                                for s in seqs], np.int32)
+            bt, lens = self.cache.block_table([rids[i] for i in idx])
+            self._note_decode_kv(rids, Bp, bt.shape[1])
+            srows, conv, ssm = self._state_operands([rids[i] for i in idx])
+            self.rng_ctr += 1
+            seed = self.rng_seed + jnp.uint32(self.rng_ctr)
+            last, pages, slots, temps = (
+                jnp.asarray(x) for x in (last, pages, slots, temps))
+        with _span("engine.dispatch.decode"):
+            tokens, k_arena, v_arena, conv_a, ssm_a = self._step(
+                self.params, last, self.cache.k_arena, self.cache.v_arena,
+                bt, lens, pages, slots, seed, temps, conv, ssm, srows)
+        with _span("engine.commit"):
+            self.cache.commit_fused_round(rids, k_arena, v_arena,
+                                          wrote_kv=self._has_attn)
+            if self._has_ssm:
+                self.cache.state.adopt(conv_a, ssm_a)
+                self.cache.state.record_fused_write(rids)
+            # per-engine count: the queue's fused_decode counter is global
+            # to the (possibly shared) lib, this one is this engine's own
+            self.stats["fused_dispatches"] += 1
+        with _span("engine.sync"):
+            return np.asarray(tokens)[:B]  # the round's one host transfer
+
+    def _note_decode_kv(self, rids: List[int], rows: int,
+                        width: int) -> None:
+        """Pad counters of one decode launch over ``rids`` padded to
+        ``rows``: the arena tokens the real rows attend over, against
+        the rows x table width x page size the launch gathers."""
+        self.stats["decode_kv_tokens"] += sum(self.cache.seqs[r].length
+                                              for r in rids)
+        self.stats["decode_kv_slots"] += rows * width * self.cache.page_size
+
+    def _note_chunk(self, batch: List[tuple], c: dict) -> None:
+        """Pad counters of one chunk launch: its real chunk tokens
+        against rows x chunk bucket."""
+        self.stats["prefill_chunks"] += len(batch)
+        self.stats["chunk_tokens"] += sum(clen for _, clen in batch)
+        self.stats["chunk_token_slots"] += c["toks"].size
 
     def _decode_block(self, max_allowed: int) -> int:
         """Up to ``decode_block_rounds`` decode rounds in ONE dispatch —
@@ -1257,73 +1349,88 @@ class PagedEngine:
         if max(steps) <= 1:
             self._decode_round()
             return 1
-        for r, n in zip(rids, steps):
-            self.cache.reserve_tokens(self.cache.seqs[r], n)
-        self._reserved_tails.clear()
-        self.cache.flush_pending()
-        B = len(rids)
-        Bp = _bucket_pow2(B)
-        idx = list(range(B)) + [0] * (Bp - B)   # pad rows duplicate row 0
-        ps = self.cache.page_size
-        pages = np.zeros((Bp, K), np.int32)
-        slots = np.zeros((Bp, K), np.int32)
-        last = np.zeros((Bp,), np.int32)
-        steps_arr = np.zeros((Bp,), np.int32)
-        eos = np.full((Bp,), -1, np.int32)
-        temps = np.zeros((Bp,), np.float32)
-        for row, i in enumerate(idx):
-            r = rids[i]
-            req, seq, n = self.active[r], self.cache.seqs[r], steps[i]
-            for t in range(K):
-                pos = seq.length + min(t, n - 1)
-                pages[row, t] = seq.pages[pos // ps]
-                slots[row, t] = pos % ps
-            last[row] = req.out_tokens[-1]
-            steps_arr[row] = n
-            if req.eos_token_id is not None:
-                eos[row] = req.eos_token_id
-            temps[row] = req.temperature
-        # table spans the reserved pages (block_table covers the full
-        # page list); lens stay the committed lengths — the loop carries
-        # them forward round by round
-        bt, lens = self.cache.block_table([rids[i] for i in idx])
-        # K sequential rounds consume K seeds: pass round 0's, the loop
-        # derives round t's by offset — the same stream a round-at-a-time
-        # run would draw
-        self.rng_ctr += K
-        seed = self.rng_seed + jnp.uint32(self.rng_ctr - K + 1)
-        srows, conv, ssm = self._state_operands([rids[i] for i in idx])
-        tokens, k_arena, v_arena, conv_a, ssm_a = self._block_step(
-            self.params, jnp.asarray(last), jnp.asarray(steps_arr),
-            self.cache.k_arena, self.cache.v_arena, bt, lens,
-            jnp.asarray(pages), jnp.asarray(slots), jnp.asarray(eos),
-            seed, jnp.asarray(temps), jnp.asarray(idx, dtype=jnp.int32),
-            conv, ssm, srows)
-        toks_np = np.asarray(tokens)[:B]   # the block's ONE host transfer
-        counts = []
-        for i, r in enumerate(rids):
-            req = self.active[r]
-            n_i = 0
-            for t in range(steps[i]):
-                tok = int(toks_np[i, t])
-                if tok < 0:                # device stopped this row earlier
-                    break
-                req.out_tokens.append(tok)
-                n_i += 1
-                if (req.eos_token_id is not None
-                        and tok == req.eos_token_id):
-                    break
-            counts.append(n_i)
-        consumed = max(counts)
-        self.cache.commit_fused_block(rids, counts, k_arena, v_arena,
-                                      rounds=consumed,
-                                      wrote_kv=self._has_attn)
-        if self._has_ssm:
-            self.cache.state.adopt(conv_a, ssm_a)
-            self.cache.state.record_fused_write(rids, rounds=consumed)
-        self.stats["decode_rounds"] += consumed
-        self.stats["tokens_out"] += sum(counts)
-        self.stats["multi_round_blocks"] += 1
+        with _span("engine.plan"):
+            for r, n in zip(rids, steps):
+                self.cache.reserve_tokens(self.cache.seqs[r], n)
+            self._reserved_tails.clear()
+        with _span("engine.flush"):
+            self.cache.flush_pending()
+        with _span("engine.plan"):
+            B = len(rids)
+            Bp = _bucket_pow2(B)
+            idx = list(range(B)) + [0] * (Bp - B)   # pad rows duplicate row 0
+            ps = self.cache.page_size
+            pages = np.zeros((Bp, K), np.int32)
+            slots = np.zeros((Bp, K), np.int32)
+            last = np.zeros((Bp,), np.int32)
+            steps_arr = np.zeros((Bp,), np.int32)
+            eos = np.full((Bp,), -1, np.int32)
+            temps = np.zeros((Bp,), np.float32)
+            for row, i in enumerate(idx):
+                r = rids[i]
+                req, seq, n = self.active[r], self.cache.seqs[r], steps[i]
+                for t in range(K):
+                    pos = seq.length + min(t, n - 1)
+                    pages[row, t] = seq.pages[pos // ps]
+                    slots[row, t] = pos % ps
+                last[row] = req.out_tokens[-1]
+                steps_arr[row] = n
+                if req.eos_token_id is not None:
+                    eos[row] = req.eos_token_id
+                temps[row] = req.temperature
+            # table spans the reserved pages (block_table covers the full
+            # page list); lens stay the committed lengths — the loop
+            # carries them forward round by round
+            bt, lens = self.cache.block_table([rids[i] for i in idx])
+            base = [self.cache.seqs[r].length for r in rids]
+            # K sequential rounds consume K seeds: pass round 0's, the
+            # loop derives round t's by offset — the same stream a
+            # round-at-a-time run would draw
+            self.rng_ctr += K
+            seed = self.rng_seed + jnp.uint32(self.rng_ctr - K + 1)
+            srows, conv, ssm = self._state_operands([rids[i] for i in idx])
+            last, steps_arr, pages, slots, eos, temps = (
+                jnp.asarray(x) for x in (last, steps_arr, pages, slots, eos,
+                                         temps))
+            rowmap = jnp.asarray(idx, dtype=jnp.int32)
+        with _span("engine.dispatch.block"):
+            tokens, k_arena, v_arena, conv_a, ssm_a = self._block_step(
+                self.params, last, steps_arr, self.cache.k_arena,
+                self.cache.v_arena, bt, lens, pages, slots, eos, seed, temps,
+                rowmap, conv, ssm, srows)
+        with _span("engine.sync"):
+            toks_np = np.asarray(tokens)[:B]   # the block's ONE transfer
+        with _span("engine.commit"):
+            counts = []
+            for i, r in enumerate(rids):
+                req = self.active[r]
+                n_i = 0
+                for t in range(steps[i]):
+                    tok = int(toks_np[i, t])
+                    if tok < 0:            # device stopped this row earlier
+                        break
+                    req.out_tokens.append(tok)
+                    n_i += 1
+                    if (req.eos_token_id is not None
+                            and tok == req.eos_token_id):
+                        break
+                counts.append(n_i)
+            consumed = max(counts)
+            self.cache.commit_fused_block(rids, counts, k_arena, v_arena,
+                                          rounds=consumed,
+                                          wrote_kv=self._has_attn)
+            if self._has_ssm:
+                self.cache.state.adopt(conv_a, ssm_a)
+                self.cache.state.record_fused_write(rids, rounds=consumed)
+            # each of the loop's rounds gathers every row at the table's
+            # width; a row attends over its growing length while alive
+            self.stats["decode_kv_tokens"] += sum(
+                n * L + n * (n - 1) // 2 for L, n in zip(base, counts))
+            self.stats["decode_kv_slots"] += (consumed * Bp * bt.shape[1]
+                                              * ps)
+            self.stats["decode_rounds"] += consumed
+            self.stats["tokens_out"] += sum(counts)
+            self.stats["multi_round_blocks"] += 1
         return consumed
 
     def _decode_round_eager(self, rids: List[int]) -> np.ndarray:
@@ -1403,12 +1510,13 @@ def _fused_decode_step(cfg, pcfg, params, last, k_arena, v_arena, bt, lens,
         compressed=compressed, conv_arena=conv_arena,
         ssm_arena=ssm_arena, srows=srows)
     if k_new is not None:
-        k_arena = rc_ops.kv_scatter_inline(
-            k_arena, pages, slots, k_new[:, :, 0].astype(k_arena.dtype),
-            use_pallas=use_pallas, interpret=interpret)
-        v_arena = rc_ops.kv_scatter_inline(
-            v_arena, pages, slots, v_new[:, :, 0].astype(v_arena.dtype),
-            use_pallas=use_pallas, interpret=interpret)
+        with jax.named_scope("kv_scatter"):
+            k_arena = rc_ops.kv_scatter_inline(
+                k_arena, pages, slots, k_new[:, :, 0].astype(k_arena.dtype),
+                use_pallas=use_pallas, interpret=interpret)
+            v_arena = rc_ops.kv_scatter_inline(
+                v_arena, pages, slots, v_new[:, :, 0].astype(v_arena.dtype),
+                use_pallas=use_pallas, interpret=interpret)
     tokens = _select_tokens(logits[:, 0], temps, seed,
                             use_pallas=use_pallas, interpret=interpret)
     return tokens, k_arena, v_arena, conv_arena, ssm_arena
@@ -1462,6 +1570,7 @@ def _fused_block_step(cfg, pcfg, params, last, steps, k_arena, v_arena, bt,
         p_t = jax.lax.dynamic_index_in_dim(pages, t, axis=1, keepdims=False)
         s_t = jax.lax.dynamic_index_in_dim(slots, t, axis=1, keepdims=False)
 
+        @jax.named_scope("kv_scatter")
         def masked_scatter(arena, new):
             old = rc_ops.kv_gather_inline(arena, p_t, s_t)
             val = jnp.where(alive[None, :, None, None],
@@ -1567,6 +1676,7 @@ def _fused_prefill_step(cfg, pcfg, params, toks, lens, k_arena, v_arena,
         conv_arena=conv_arena, ssm_arena=ssm_arena, srows=srows)
     Bp, Sp = toks.shape
 
+    @jax.named_scope("kv_scatter")
     def scatter(arena, new_all):
         L = new_all.shape[0]
         flat = new_all.reshape((L, Bp * Sp) + new_all.shape[3:])[:, src]
@@ -1610,6 +1720,7 @@ def _fused_chunk_prefill_step(cfg, pcfg, params, toks, lens, offs, k_arena,
         ssm_arena=ssm_arena, srows=srows)
     Bp, Sp = toks.shape
 
+    @jax.named_scope("kv_scatter")
     def scatter(arena, new_all):
         L = new_all.shape[0]
         flat = new_all.reshape((L, Bp * Sp) + new_all.shape[3:])[:, src]
@@ -1828,6 +1939,7 @@ def _embed_tokens(p, tokens, cfg, axis=None):
     return x
 
 
+@jax.named_scope("logits")
 def _logits_reduce(p, x, cfg, axis=None, compressed=False, fp32=True):
     """Output logits, host-local or vocab-parallel.
 
@@ -1874,19 +1986,21 @@ def _sublayer(cfg, kind, sp, x, sin, cos, attend, axis=None):
     arena shard stores."""
     h = rmsnorm(x, sp["norm"], cfg.norm_eps)
     if kind != "attn":
-        y = mlp(sp["mlp"], h, cfg.activation)
-        if axis is not None:
-            y = jax.lax.psum(y, axis)
+        with jax.named_scope("mlp"):
+            y = mlp(sp["mlp"], h, cfg.activation)
+            if axis is not None:
+                y = jax.lax.psum(y, axis)
         return x + y, None
-    q = jnp.einsum("bsd,dhk->bshk", h, cast(sp["attn"]["wq"]))
-    k = jnp.einsum("bsd,dhk->bshk", h, cast(sp["attn"]["wk"]))
-    v = jnp.einsum("bsd,dhk->bshk", h, cast(sp["attn"]["wv"]))
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
-    o = attend(q, k, v)
-    out = jnp.einsum("bshk,hkd->bsd", o, cast(sp["attn"]["wo"]))
-    if axis is not None:
-        out = jax.lax.psum(out, axis)
+    with jax.named_scope("attention"):
+        q = jnp.einsum("bsd,dhk->bshk", h, cast(sp["attn"]["wq"]))
+        k = jnp.einsum("bsd,dhk->bshk", h, cast(sp["attn"]["wk"]))
+        v = jnp.einsum("bsd,dhk->bshk", h, cast(sp["attn"]["wv"]))
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
+        o = attend(q, k, v)
+        out = jnp.einsum("bshk,hkd->bsd", o, cast(sp["attn"]["wo"]))
+        if axis is not None:
+            out = jax.lax.psum(out, axis)
     return x + out, (k, v)
 
 
@@ -1939,7 +2053,8 @@ def _run_kinds(cfg, pcfg, kinds, p_layer, x, sin, cos, attend, conv_l,
             j += 1
         elif kind == "moe":
             h = rmsnorm(x, sp["norm"], cfg.norm_eps)
-            out, _aux = moe_mod.moe_layer(cfg, pcfg, sp["moe"], h)
+            with jax.named_scope("mlp"):
+                out, _aux = moe_mod.moe_layer(cfg, pcfg, sp["moe"], h)
             x = x + out
         else:
             x, kv = _sublayer(cfg, kind, sp, x, sin, cos, attend,

@@ -49,6 +49,7 @@ from dataclasses import dataclass, field
 from typing import AsyncIterator, Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.engine import PagedEngine, Request
 
@@ -305,7 +306,11 @@ class AsyncServer:
 
     async def _loop(self) -> None:
         while True:
-            self._admit()
+            # profiler spans (server.admit, server.publish) beside the
+            # engine's own: with no profiler running they cost their
+            # enter/exit alone
+            with TraceAnnotation("server.admit"):
+                self._admit()
             if not self.engine.has_work:
                 if self._closing and not self._waiting:
                     return
@@ -324,7 +329,8 @@ class AsyncServer:
             dt = _now_ms() - t0
             self._observe_round(dt, self.engine.stats["tokens_out"]
                                 - before_toks)
-            self._publish(finished)
+            with TraceAnnotation("server.publish"):
+                self._publish(finished)
             # let arrivals (and consumers) run before the next round
             await asyncio.sleep(0)
 
