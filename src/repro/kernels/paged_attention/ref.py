@@ -1,6 +1,7 @@
 """Pure-jnp oracle for paged decode attention.
 
-Mirrors the Pallas kernel's contract, including the fusion hooks: an
+Mirrors the Pallas kernel's contract — layer ``layer`` of a stacked
+``(layers, pages, page, KVH, D)`` arena — including the fusion hooks: an
 optional fresh current-token K/V (``k_self``/``v_self``) merged at
 position ``lengths[b]``, and optional ``(m, l)`` running log-sum-exp
 statistics (``return_lse``) defined exactly as the kernel accumulates
@@ -17,22 +18,22 @@ _NEG_INF = -1e30
 
 
 def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
-                    block_tables: jax.Array, lengths: jax.Array, *,
+                    layer, block_tables: jax.Array, lengths: jax.Array, *,
                     sm_scale: float | None = None,
                     k_self: jax.Array | None = None,
                     v_self: jax.Array | None = None,
                     return_lse: bool = False):
     bsz, h, d = q.shape
-    pages, page_size, kvh, _ = k_arena.shape
+    page_size, kvh = k_arena.shape[2:4]
     groups = h // kvh
     if sm_scale is None:
         sm_scale = d ** -0.5
     max_pages = block_tables.shape[1]
     max_len = max_pages * page_size
 
-    # Gather each sequence's logical KV from its pages.
-    k = k_arena[block_tables]                    # (B, P, page, KVH, D)
-    v = v_arena[block_tables]
+    # Gather each sequence's logical KV from its pages of ``layer``.
+    k = k_arena[layer, block_tables]             # (B, P, page, KVH, D)
+    v = v_arena[layer, block_tables]
     k = k.reshape(bsz, max_len, kvh, d)
     v = v.reshape(bsz, max_len, kvh, d)
     valid = jnp.arange(max_len)[None, :] < lengths[:, None]   # (B, S)

@@ -13,8 +13,10 @@ Sampling consumes the D-RaNGe TPU generator (`pim_rand`).
 A decode round is ONE compiled dispatch (the fused decode step):
 
 * the layer loop is a ``jax.lax.scan`` over the stacked ``group0``
-  params and the per-layer arena slices, so the traced program is O(1)
-  in depth;
+  params and the layer index, so the traced program is O(1) in depth;
+  the arenas are loop-invariant and the paged-attention kernel reads
+  each layer's pages in place (on a TPU always, elsewhere with
+  ``use_pallas``; the jnp reference otherwise);
 * the current token's K/V merge into attention happens *inside* the
   paged-attention kernel (``k_self``/``v_self``) — no post-kernel pass
   re-reads the arena history;
@@ -341,7 +343,9 @@ class PagedEngine:
                                   mesh=mesh, prefix_cache=prefix_cache)
         # Pallas kernels (True) or their jnp references; how a kernel
         # runs — compiled on a TPU, interpreted elsewhere — is the
-        # backend's call (repro.kernels.common.interpret_mode)
+        # backend's call (repro.kernels.common.interpret_mode).  Decode
+        # attention takes its kernel on a TPU whatever this says
+        # (pa_ops.uses_kernel)
         self.use_pallas = use_pallas
         self.fused = fused
         self.fused_prefill = fused_prefill
@@ -390,8 +394,10 @@ class PagedEngine:
                       "prefix_declined_ssm": 0,
                       # useful over attempted work of the padded launches:
                       # arena tokens the decode rows attend over against
-                      # the rows x table width x page size gathered, and
-                      # real chunk tokens against rows x chunk bucket
+                      # the token slots the launches read (each padded
+                      # row's length rounded up to a page on the kernel;
+                      # rows x table width x page size on the reference),
+                      # and real chunk tokens against rows x chunk bucket
                       "decode_kv_tokens": 0, "decode_kv_slots": 0,
                       "chunk_tokens": 0, "chunk_token_slots": 0}
         self._step = self._build_fused_step() if fused else None
@@ -1033,7 +1039,8 @@ class PagedEngine:
             d_slots = np.asarray([s.length % self.cache.page_size
                                   for s in seqs], np.int32)
             d_bt, d_lens = self.cache.block_table([d_rids[i] for i in idx])
-            self._note_decode_kv(d_rids, Bp, d_bt.shape[1])
+            self._note_decode_kv(d_rids, [s.length for s in seqs],
+                                 d_bt.shape[1])
             c_srows, conv, ssm = self._state_operands(c["rids"])
             d_srows, _, _ = self._state_operands([d_rids[i] for i in idx])
             self.rng_ctr += 1
@@ -1283,7 +1290,8 @@ class PagedEngine:
             slots = np.asarray([s.length % self.cache.page_size
                                 for s in seqs], np.int32)
             bt, lens = self.cache.block_table([rids[i] for i in idx])
-            self._note_decode_kv(rids, Bp, bt.shape[1])
+            self._note_decode_kv(rids, [s.length for s in seqs],
+                                 bt.shape[1])
             srows, conv, ssm = self._state_operands([rids[i] for i in idx])
             self.rng_ctr += 1
             seed = self.rng_seed + jnp.uint32(self.rng_ctr)
@@ -1305,14 +1313,16 @@ class PagedEngine:
         with _span("engine.sync"):
             return np.asarray(tokens)[:B]  # the round's one host transfer
 
-    def _note_decode_kv(self, rids: List[int], rows: int,
+    def _note_decode_kv(self, rids: List[int], row_lens: List[int],
                         width: int) -> None:
-        """Pad counters of one decode launch over ``rids`` padded to
-        ``rows``: the arena tokens the real rows attend over, against
-        the rows x table width x page size the launch gathers."""
+        """Pad counters of one decode launch over ``rids``, whose padded
+        rows have lengths ``row_lens``: the arena tokens the real rows
+        attend over, against the token slots the launch reads
+        (:func:`pa_ops.kv_slots_read`)."""
         self.stats["decode_kv_tokens"] += sum(self.cache.seqs[r].length
                                               for r in rids)
-        self.stats["decode_kv_slots"] += rows * width * self.cache.page_size
+        self.stats["decode_kv_slots"] += pa_ops.kv_slots_read(
+            row_lens, width, self.cache.page_size, self.use_pallas)
 
     def _note_chunk(self, batch: List[tuple], c: dict) -> None:
         """Pad counters of one chunk launch: its real chunk tokens
@@ -1422,12 +1432,15 @@ class PagedEngine:
             if self._has_ssm:
                 self.cache.state.adopt(conv_a, ssm_a)
                 self.cache.state.record_fused_write(rids, rounds=consumed)
-            # each of the loop's rounds gathers every row at the table's
-            # width; a row attends over its growing length while alive
+            # each of the loop's rounds reads every padded row; a row
+            # attends over its growing length while alive
             self.stats["decode_kv_tokens"] += sum(
                 n * L + n * (n - 1) // 2 for L, n in zip(base, counts))
-            self.stats["decode_kv_slots"] += (consumed * Bp * bt.shape[1]
-                                              * ps)
+            self.stats["decode_kv_slots"] += sum(
+                pa_ops.kv_slots_read(
+                    [base[i] + min(t, counts[i]) for i in idx],
+                    bt.shape[1], ps, self.use_pallas)
+                for t in range(consumed))
             self.stats["decode_rounds"] += consumed
             self.stats["tokens_out"] += sum(counts)
             self.stats["multi_round_blocks"] += 1
@@ -2072,8 +2085,10 @@ def _paged_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
                           compressed: bool = False, conv_arena=None,
                           ssm_arena=None, srows=None, alive=None):
     """Decoder forward for one token: ``lax.scan`` over the stacked
-    layer params and the per-layer arena slices — O(1) program size in
-    depth, and the current token's K/V merges inside the paged kernel.
+    layer params and the layer index — O(1) program size in depth.  The
+    arenas are not scanned: each step's paged attention reads its
+    layer's pages in place in the stacked arena, and the current token's
+    K/V merges inside the paged kernel.
     With SSM sublayers (``conv_arena`` set) the scan's xs extend with
     the per-step state-arena slices and the updated arenas ride out as
     stacked ys — still one scan, zero extra launches.
@@ -2095,16 +2110,17 @@ def _paged_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
 
     def body(x, xs):
         if has_ssm:
-            p_layer, k_l, v_l, conv_l, ssm_l = xs
+            p_layer, li, conv_l, ssm_l = xs
         else:
-            p_layer, k_l, v_l = xs
+            p_layer, li = xs
             conv_l = ssm_l = None
 
         def attend(q, k, v):
-            # one token against the arena pages, with the fresh K/V
-            # (not yet written) merged in-kernel
+            # one token against layer li's pages, read in place in the
+            # stacked arena, with the fresh K/V (not yet written) merged
+            # in-kernel
             o = pa_ops.paged_attention_inline(
-                q[:, 0], k_l, v_l, block_tables, lengths,
+                q[:, 0], k_arena, v_arena, li, block_tables, lengths,
                 sm_scale=hd ** -0.5, use_pallas=use_pallas,
                 interpret=interpret, k_self=k[:, 0], v_self=v[:, 0])
             return o[:, None]
@@ -2119,7 +2135,8 @@ def _paged_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
             ys += ((conv_l, ssm_l),)
         return x, ys
 
-    xs = (params["group0"], k_arena, v_arena)
+    # the arenas stay loop-invariant operands: xs carries the layer index
+    xs = (params["group0"], jnp.arange(k_arena.shape[0], dtype=jnp.int32))
     if has_ssm:
         xs += (conv_arena, ssm_arena)
     x, ys = jax.lax.scan(body, x, xs)
@@ -2156,10 +2173,10 @@ def _eager_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
     L, kinds = T.layer_groups(cfg)[0]
     has_ssm = conv_arena is not None
 
-    def layer_attend(k_l, v_l):
+    def layer_attend(li):
         def attend(q, k, v):
             o = pa_ops.paged_attention(
-                q[:, 0], k_l, v_l, block_tables, lengths,
+                q[:, 0], k_arena, v_arena, li, block_tables, lengths,
                 sm_scale=hd ** -0.5, use_pallas=use_pallas,
                 interpret=interpret, k_self=k[:, 0], v_self=v[:, 0])
             return o[:, None]
@@ -2169,7 +2186,7 @@ def _eager_decode_forward(cfg: ModelConfig, pcfg, params, tokens, k_arena,
     conv_news, ssm_news = [], []
     for li in range(L):
         p_layer = jax.tree.map(lambda a: a[li], gparams)
-        attend = layer_attend(k_arena[li], v_arena[li])
+        attend = layer_attend(li)
         conv_l = conv_arena[li] if has_ssm else None
         ssm_l = ssm_arena[li] if has_ssm else None
         x, kv, conv_l, ssm_l = _run_kinds(

@@ -319,6 +319,21 @@ class TestFlashPrefixKV:
                                        rtol=3e-5, atol=3e-5)
 
 
+def _stacked_arenas(rng, layers, pages, ps, kvh, d, dtype=np.float32):
+    """Two (layers, pages, ps, kvh, d) arenas: the layout the serving
+    engine stacks and the kernel reads in place."""
+    shape = (layers, pages, ps, kvh, d)
+    return (jnp.asarray(rng.normal(size=shape).astype(dtype)),
+            jnp.asarray(rng.normal(size=shape).astype(dtype)))
+
+
+# float32 arenas: the kernel and the oracle differ only in summation
+# order.  bfloat16 arenas: the kernel also rounds the probabilities to
+# bfloat16 for the PV product, as the compiled oracle does at the TPU's
+# default matmul precision; the oracle here keeps them in float32.
+PA_TOL = {np.float32: 3e-5, jnp.bfloat16: 2e-2}
+
+
 class TestPagedAttention:
     @settings(**SETTINGS)
     @given(b=st.integers(1, 3), kvh=st.sampled_from([1, 2, 4]),
@@ -329,68 +344,103 @@ class TestPagedAttention:
         d = 32
         rng = np.random.default_rng(seed)
         q = jnp.asarray(rng.normal(size=(b, h, d)).astype(np.float32))
-        total = npages * b + 1
-        ka = jnp.asarray(rng.normal(size=(total, ps, kvh, d)).astype(np.float32))
-        va = jnp.asarray(rng.normal(size=(total, ps, kvh, d)).astype(np.float32))
+        layers = 3
+        ka, va = _stacked_arenas(rng, layers, npages * b + 1, ps, kvh, d)
+        layer = int(rng.integers(0, layers))
         bt = jnp.asarray(rng.permutation(npages * b).reshape(b, npages).astype(np.int32))
-        lengths = jnp.asarray(rng.integers(1, npages * ps + 1, b).astype(np.int32))
-        out = pa.paged_attention(q, ka, va, bt, lengths, interpret=True)
-        expect = pa_ref.paged_attention(q, ka, va, bt, lengths)
+        lengths = jnp.asarray(rng.integers(0, npages * ps + 1, b).astype(np.int32))
+        out = pa.paged_attention(q, ka, va, layer, bt, lengths, interpret=True)
+        expect = pa_ref.paged_attention(q, ka, va, layer, bt, lengths)
         np.testing.assert_allclose(out, expect, rtol=3e-5, atol=3e-5)
 
 
 class TestPagedAttentionFusion:
     """The decode-fusion hooks: LSE-returning variant and the in-kernel
-    current-token (self) merge, vs the jnp oracle and a dense oracle."""
+    current-token (self) merge, vs the jnp oracle and a dense oracle,
+    over stacked arenas read at a layer index."""
 
     @staticmethod
-    def _setup(seed=0, b=3, kvh=2, g=2, ps=8, npages=4, d=32):
+    def _setup(seed=0, b=3, kvh=2, g=2, ps=8, npages=4, d=32, layers=3,
+               lens=None, dtype=np.float32):
         h = kvh * g
         rng = np.random.default_rng(seed)
-        q = jnp.asarray(rng.normal(size=(b, h, d)).astype(np.float32))
-        total = npages * b + 1
-        ka = jnp.asarray(rng.normal(size=(total, ps, kvh, d)).astype(np.float32))
-        va = jnp.asarray(rng.normal(size=(total, ps, kvh, d)).astype(np.float32))
+        q = jnp.asarray(rng.normal(size=(b, h, d)).astype(dtype))
+        ka, va = _stacked_arenas(rng, layers, npages * b + 1, ps, kvh, d,
+                                 dtype)
         bt = jnp.asarray(rng.permutation(npages * b).reshape(b, npages).astype(np.int32))
-        lens = jnp.asarray(rng.integers(1, npages * ps, b).astype(np.int32))
-        ks = jnp.asarray(rng.normal(size=(b, kvh, d)).astype(np.float32))
-        vs = jnp.asarray(rng.normal(size=(b, kvh, d)).astype(np.float32))
+        if lens is None:
+            lens = rng.integers(1, npages * ps, b)
+        lens = jnp.asarray(np.asarray(lens, np.int32))
+        ks = jnp.asarray(rng.normal(size=(b, kvh, d)).astype(dtype))
+        vs = jnp.asarray(rng.normal(size=(b, kvh, d)).astype(dtype))
         return q, ka, va, bt, lens, ks, vs
 
-    def test_lse_variant_matches_ref(self):
+    @pytest.mark.parametrize("kvh,g,layer,with_self", [
+        (2, 2, 0, False), (2, 4, 2, True), (8, 4, 1, False),
+        (8, 4, 2, True)])
+    def test_lse_variant_matches_ref(self, kvh, g, layer, with_self):
         for seed in range(3):
-            q, ka, va, bt, lens, _, _ = self._setup(seed)
-            o1, m1, l1 = pa.paged_attention(q, ka, va, bt, lens,
-                                            interpret=True, return_lse=True)
-            o2, m2, l2 = pa_ref.paged_attention(q, ka, va, bt, lens,
-                                                return_lse=True)
+            q, ka, va, bt, lens, ks, vs = self._setup(seed, kvh=kvh, g=g)
+            kw = dict(k_self=ks, v_self=vs) if with_self else {}
+            o1, m1, l1 = pa.paged_attention(q, ka, va, layer, bt, lens,
+                                            interpret=True, return_lse=True,
+                                            **kw)
+            o2, m2, l2 = pa_ref.paged_attention(q, ka, va, layer, bt, lens,
+                                                return_lse=True, **kw)
             np.testing.assert_allclose(o1, o2, rtol=3e-5, atol=3e-5)
             np.testing.assert_allclose(m1, m2, rtol=3e-5, atol=3e-5)
             np.testing.assert_allclose(l1, l2, rtol=3e-5, atol=3e-5)
 
-    def test_self_token_merge_matches_ref(self):
-        for seed in range(3):
-            q, ka, va, bt, lens, ks, vs = self._setup(seed)
-            out = pa.paged_attention(q, ka, va, bt, lens, interpret=True,
-                                     k_self=ks, v_self=vs)
-            expect = pa_ref.paged_attention(q, ka, va, bt, lens,
-                                            k_self=ks, v_self=vs)
-            np.testing.assert_allclose(out, expect, rtol=3e-5, atol=3e-5)
+    # pages of 16 tokens in a 16-page table, read in blocks of 64 tokens
+    # (set small here, so that a row spans several blocks): 64 ends a
+    # block, 65 fetches one page of the next, 256 fills the table
+    EDGE_LENS = [0, 1, 15, 16, 17, 64, 65, 256]
 
-    def test_self_token_merge_matches_dense_oracle(self):
+    @pytest.mark.parametrize("kvh,lens,pad,dtype", [
+        (2, None, 0, np.float32),
+        (8, EDGE_LENS, 0, np.float32),
+        (2, EDGE_LENS, 0, np.float32),
+        # two pad rows repeat row 0, as the engine pads a batch
+        (8, [200, 17, 1], 2, np.float32),
+        (8, EDGE_LENS, 0, jnp.bfloat16)])
+    def test_self_token_merge_matches_ref(self, kvh, lens, pad, dtype,
+                                          monkeypatch):
+        kw = {}
+        if lens:
+            monkeypatch.setattr(pa, "_BLOCK_TOKENS", 64)
+            kw = dict(ps=16, npages=16, b=len(lens) + pad)
+        if pad:
+            lens = lens + [lens[0]] * pad
+        for seed, layer in ((0, 0), (1, 1), (2, 2)):
+            q, ka, va, bt, ln, ks, vs = self._setup(
+                seed, kvh=kvh, g=4, lens=lens, dtype=dtype, **kw)
+            if pad:
+                rows = np.r_[np.arange(len(lens) - pad), np.zeros(pad, int)]
+                q, bt, ks, vs = (x[rows] for x in (q, bt, ks, vs))
+            out = pa.paged_attention(q, ka, va, layer, bt, ln,
+                                     interpret=True, k_self=ks, v_self=vs)
+            expect = pa_ref.paged_attention(q, ka, va, layer, bt, ln,
+                                            k_self=ks, v_self=vs)
+            tol = PA_TOL[dtype]
+            np.testing.assert_allclose(np.asarray(out, np.float32),
+                                       np.asarray(expect, np.float32),
+                                       rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_self_token_merge_matches_dense_oracle(self, layer):
         # independent oracle: gather history + append self token, then a
         # plain (non-streaming) softmax per sequence
         b, kvh, g, d = 2, 2, 2, 32
         h = kvh * g
         q, ka, va, bt, lens, ks, vs = self._setup(7, b=b, kvh=kvh, g=g, d=d)
-        out = np.asarray(pa.paged_attention(q, ka, va, bt, lens,
+        out = np.asarray(pa.paged_attention(q, ka, va, layer, bt, lens,
                                             interpret=True,
                                             k_self=ks, v_self=vs))
         scale = d ** -0.5
         for i in range(b):
             L = int(lens[i])
-            kk = np.asarray(ka[bt[i]]).reshape(-1, kvh, d)[:L]
-            vv = np.asarray(va[bt[i]]).reshape(-1, kvh, d)[:L]
+            kk = np.asarray(ka[layer][bt[i]]).reshape(-1, kvh, d)[:L]
+            vv = np.asarray(va[layer][bt[i]]).reshape(-1, kvh, d)[:L]
             kk = np.concatenate([kk, np.asarray(ks[i])[None]], 0)
             vv = np.concatenate([vv, np.asarray(vs[i])[None]], 0)
             qi = np.asarray(q[i]).reshape(kvh, g, d)
@@ -399,3 +449,17 @@ class TestPagedAttentionFusion:
             p /= p.sum(-1, keepdims=True)
             oo = np.einsum("kgs,skd->kgd", p, vv).reshape(h, d)
             np.testing.assert_allclose(out[i], oo, rtol=3e-5, atol=3e-5)
+
+    @pytest.mark.parametrize("lens,width,expect", [
+        # pages of 16 tokens: 0+1+1+1+2+4+5+16 pages
+        ([0, 1, 15, 16, 17, 64, 65, 256], 16, 30 * 16),
+        ([300, 1], 256, (19 + 1) * 16),
+        # a length past the table reads the table
+        ([5, 300], 4, (1 + 4) * 16)])
+    def test_kv_slots_read_counts_the_pages_below_each_length(
+            self, lens, width, expect):
+        from repro.kernels.paged_attention import ops as pa_ops
+        assert pa_ops.kv_slots_read(lens, width, 16, True) == expect
+        if jax.default_backend() != "tpu":
+            assert pa_ops.kv_slots_read(lens, width, 16, False) == (
+                len(lens) * width * 16)

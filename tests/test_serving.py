@@ -385,7 +385,11 @@ class TestFusedDecode:
             outs.append((tuple(res[0]), tuple(res[1])))
         assert outs[0] == outs[1]
 
-    def test_scan_forward_matches_eager_logits(self, model, rng):
+    # use_pallas: both forwards take the paged kernel (interpreted
+    # here), which reads the stacked arena at the scan's layer index
+    @pytest.mark.parametrize("use_pallas", [False, True])
+    def test_scan_forward_matches_eager_logits(self, model, rng,
+                                               use_pallas):
         from repro.serving import engine as E
         cfg, params = model
         eng = PagedEngine(cfg, params, page_size=4, num_pages=64)
@@ -404,9 +408,9 @@ class TestFusedDecode:
         args = (cfg, eng.pcfg, params, last, eng.cache.k_arena,
                 eng.cache.v_arena, bt, lens)
         lg_s, k_s, v_s, _, _ = E._paged_decode_forward(
-            *args, use_pallas=False, interpret=True)
+            *args, use_pallas=use_pallas, interpret=True)
         lg_e, k_e, v_e, _, _ = E._eager_decode_forward(
-            *args, use_pallas=False, interpret=True)
+            *args, use_pallas=use_pallas, interpret=True)
         # fp32 logits over bf16 activations: scan vs unrolled loops may
         # fuse/round differently, so parity holds at bf16 resolution
         np.testing.assert_allclose(np.asarray(lg_s), np.asarray(lg_e),

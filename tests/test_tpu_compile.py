@@ -70,18 +70,72 @@ def _assert_kernel(hlo):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("k_self,return_lse", [
-    (False, False), (True, False), (False, True), (True, True)])
-def test_paged_attention(compile_tpu, k_self, return_lse):
-    def fn(q, ka, va, bt, lens, ks, vs):
-        return pa.paged_attention(q, ka, va, bt, lens, interpret=False,
+@pytest.mark.parametrize("k_self,return_lse,kvh", [
+    (False, False, KVH), (True, False, KVH), (False, True, KVH),
+    (True, True, KVH),
+    # a quarter of granite's KV heads: one shard of a four-way mesh
+    (True, False, KVH // 4)])
+def test_paged_attention(compile_tpu, k_self, return_lse, kvh):
+    def fn(q, ka, va, li, bt, lens, ks, vs):
+        return pa.paged_attention(q, ka, va, li, bt, lens, interpret=False,
                                   k_self=ks if k_self else None,
                                   v_self=vs if k_self else None,
                                   return_lse=return_lse)
-    arena = ((PAGES, PAGE, KVH, HD), BF16)
+    heads = HEADS * kvh // KVH
+    arena = ((LAYERS, PAGES, PAGE, kvh, HD), BF16)
     _assert_kernel(compile_tpu(
-        fn, ((BATCH, HEADS, HD), BF16), arena, arena, ((BATCH, 64), I32),
-        IDX, ((BATCH, KVH, HD), BF16), ((BATCH, KVH, HD), BF16)))
+        fn, ((BATCH, heads, HD), BF16), arena, arena, ((), I32),
+        ((BATCH, 64), I32), IDX, ((BATCH, kvh, HD), BF16),
+        ((BATCH, kvh, HD), BF16)))
+
+
+def test_decode_step_reads_the_arena_in_place(compile_tpu, topo,
+                                              monkeypatch):
+    """The whole decode step at granite widths, as one chip serves it
+    (20 layers, 3,584 pages, batch 16, a 256-page table): the layer loop
+    hands the stacked arena to the paged kernel, so no op outputs an
+    arena layer or a gathered KV.  A step that slices each layer out of
+    the scan's xs and gathers through the jnp reference holds 405.6 MB
+    of temporaries at these shapes; this one holds under 16 MB."""
+    import dataclasses
+    import functools
+    import re
+
+    from repro.configs import ParallelConfig
+    from repro.kernels.paged_attention import ops as pa_ops
+    from repro.models import transformer as T
+    from repro.models.params import abstract_params
+    from repro.serving import engine as E
+
+    # the described chip is not the backend here: take the kernel the
+    # TPU backend would take
+    monkeypatch.setattr(pa_ops, "uses_kernel", lambda use_pallas: True)
+    layers, pages, width = 20, 3584, 256
+    cfg = dataclasses.replace(ARCHS["granite-3-8b"], num_layers=layers)
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          abstract_params(T.model_defs(cfg), BF16))
+    arena = sds((layers, pages, PAGE, KVH, HD), BF16)
+    idx = sds((BATCH,), I32)
+    step = functools.partial(
+        E._fused_decode_step, cfg,
+        ParallelConfig(attention_impl="naive", remat="none"),
+        use_pallas=False, interpret=False)
+    compiled = jax.jit(step, donate_argnums=(2, 3)).lower(
+        params, sds((BATCH, 1), I32), arena, arena, sds((BATCH, width), I32),
+        idx, idx, idx, sds((2,), jnp.uint32), sds((BATCH,), jnp.float32)
+    ).compile()
+    hlo = compiled.as_text()
+    _assert_kernel(hlo)
+    for shape in (f"bf16[{pages},{PAGE},{KVH},{HD}]",
+                  f"bf16[{BATCH},{width * PAGE},{KVH},{HD}]",
+                  f"bf16[{BATCH},{width * PAGE + 1},{KVH},{HD}]"):
+        assert not re.search(r"= " + re.escape(shape) + r"\{", hlo), shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
 @pytest.mark.parametrize("prefix", [False, True])
@@ -180,3 +234,41 @@ def test_granite_widths_match_config():
     cfg = ARCHS["granite-3-8b"]
     assert (cfg.num_kv_heads, cfg.num_heads,
             cfg.resolved_head_dim) == (KVH, HEADS, HD)
+
+
+def test_kernel_bodies_hold_no_checkout_path(topo, monkeypatch, tmp_path):
+    """The persistent compile cache keys a program on each kernel's
+    Mosaic body, which keeps the source locations of the frames that
+    traced it.  After ``enable_compile_cache`` they are relative to the
+    checkout, so one commit keys the same programs wherever it is
+    checked out."""
+    import base64
+    import re
+
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def body():
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+        arena = sds((2, 8, PAGE, KVH, HD), BF16)
+        text = jax.jit(
+            lambda q, ka, va, bt, lens: pa.paged_attention(
+                q, ka, va, 1, bt, lens, interpret=False)
+        ).lower(sds((2, HEADS, HD), BF16), arena, arena, sds((2, 4), I32),
+                sds((2,), I32)).as_text()
+        enc = re.search(r'body\\22: \\22([A-Za-z0-9+/=]+)', text).group(1)
+        return base64.b64decode(enc)
+
+    root = str(compile_cache.ROOT).encode()
+    try:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", None)
+        assert root in body()
+        compile_cache.enable_compile_cache()
+        relative = body()
+    finally:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", None)
+    assert root not in relative
+    assert b"src/repro/kernels/paged_attention/paged_attention.py" in relative
