@@ -3,16 +3,18 @@ reference.
 
 Once the window has closed and the engine is gone, a sample of the
 finished requests, drawn from the seed and always holding the longest,
-is run through the float32 reference (bench/reference) over its prompt
-and its served tokens.  At each served position the number compared is
+is run through the float32 reference of the configuration's architecture
+module (its ``logits_at``, bench/core/arch.py) over its prompt and its
+served tokens.  At each served position the number compared is
 the gap by which the served token's reference logit lies below the
 reference's best; the cell's reading is the widest gap in the sample.
 Greedy decoding in bfloat16 serves the reference's best token or one
 within rounding of it, so the gap stays small; a wrong token, a wrong
 cache page or a missing collective leaves it at the logits' own scale.
 
-The control (``control_gaps``) reads the same gap for the token that a
-float8 copy of the reference puts first.
+The control (``control_gaps``) reads the same gap for the token that the
+module's control, the reference one precision step lower (float8 for
+bfloat16), puts first.
 """
 
 from __future__ import annotations
@@ -22,7 +24,6 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 from bench.core.traffic import seed_list
-from bench.reference import dense_gqa
 
 
 def sample(records, seed: int, min_tokens: int, max_seqs: int) -> List:
@@ -54,23 +55,23 @@ def _inputs(picked):
     return seqs, pos, tgt
 
 
-def served_gap(cfg: Dict, seed: int, picked: Sequence) -> Dict:
+def served_gap(arch, cfg: Dict, seed: int, picked: Sequence) -> Dict:
     """Widest gap of a served token below the reference's best."""
     seqs, pos, tgt = _inputs(picked)
     worst, n = 0.0, 0
-    for lg, t in zip(dense_gqa.logits_at(cfg, seed, seqs, pos), tgt):
+    for lg, t in zip(arch.logits_at(cfg, seed, seqs, pos), tgt):
         gap = lg.max(-1) - np.take_along_axis(lg, t[:, None], -1)[:, 0]
         worst = max(worst, float(gap.max()))
         n += len(t)
     return {"max_logit_gap": worst, "tokens": n}
 
 
-def control_gaps(cfg: Dict, seed: int, picked: Sequence) -> Dict:
+def control_gaps(arch, cfg: Dict, seed: int, picked: Sequence) -> Dict:
     """The control: at the same positions, the gap of the token the
-    float8 reference puts first."""
+    module's control puts first."""
     seqs, pos, _ = _inputs(picked)
-    ref = dense_gqa.logits_at(cfg, seed, seqs, pos)
-    ctl = dense_gqa.logits_at(cfg, seed, seqs, pos, quant=True)
+    ctl = arch.logits_at(cfg, seed, seqs, pos, quant=True)
+    ref = arch.logits_at(cfg, seed, seqs, pos)
     worst, n = 0.0, 0
     for r, c in zip(ref, ctl):
         pick = c.argmax(-1)

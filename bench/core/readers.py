@@ -2,10 +2,12 @@
 
 A reader is ``read(ctx) -> float | None``: None when the run holds
 nothing for it to read (the harness then leaves the metric out).
-``ctx`` carries the run: ``cfg`` (configuration file), ``dep`` (its
-deployment), ``chips``, ``peak`` (bench/core/peaks.py), ``setup_s``,
-``seconds``, ``records`` (bench/core/drive.Record), ``win``
-(drive.Window) and ``trace`` (bench/core/trace.Summary or None).
+``ctx`` carries the run: ``cfg`` (configuration file), ``arch`` (its
+architecture module, bench/core/arch.py), ``dep`` (its deployment),
+``chips``, ``peak`` (bench/core/peaks.py), ``setup_s``, ``seconds``,
+``records`` (bench/core/drive.Record), ``win`` (drive.Window), ``trace``
+(bench/core/trace.Summary or None) and ``trace_dir`` (where the profiler
+wrote the trace, or None).
 """
 
 from __future__ import annotations
@@ -13,8 +15,6 @@ from __future__ import annotations
 from typing import List, Optional
 
 import numpy as np
-
-from bench.core import flops
 
 
 def pct(values: List[float], q: float) -> Optional[float]:
@@ -63,14 +63,15 @@ def tokens_between(ctx, lo: float, hi: float) -> int:
 
 
 def required_flops(ctx, lo: float, hi: float) -> float:
-    """FLOPs the model required for the tokens stamped in [lo, hi)."""
-    total = 0.0
+    """FLOPs the model required for the tokens stamped in [lo, hi), as
+    the architecture module counts them."""
+    arch, total = ctx.arch, 0.0
     for r in ctx.records:
         P = len(r.prompt)
         for j, t in enumerate(r.token_ms):
             if lo <= t < hi:
-                total += (flops.prefill_flops(ctx.cfg, P, r.cached) if j == 0
-                          else flops.decode_flops(ctx.cfg, P + j))
+                total += (arch.prefill_flops(ctx.cfg, P, r.cached) if j == 0
+                          else arch.decode_flops(ctx.cfg, P + j))
     return total
 
 

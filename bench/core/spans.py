@@ -32,7 +32,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from bench.core.trace import _module_name, clip, intersect, length, union
 
-ROOT = Path(__file__).resolve().parents[2]
 PROGRAM = ("engine.", "server.")
 ROUND = "engine.round"
 # ops whose interval holds other ops' (a loop, a call): not leaves
@@ -313,14 +312,13 @@ def _pairs(a, b):
 def of(ctx) -> Optional[Program]:
     """The run's ``Program``, read once and kept on ``ctx``: None in an
     untraced run, or when the trace found is not the one ``ctx.trace``
-    summarised (its window differs).  The trace is where ``bench/run.py``
-    writes it, unless ``ctx.trace_dir`` says otherwise."""
+    summarised (its window differs).  The trace is read from
+    ``ctx.trace_dir``, where ``bench/run.py`` had the profiler write it."""
     if getattr(ctx, "trace", None) is None:
         return None
     if not hasattr(ctx, "program"):
-        log_dir = getattr(ctx, "trace_dir", ROOT / "bench_out" / "trace")
         try:
-            prog = summarise(load(str(log_dir)))
+            prog = summarise(load(str(ctx.trace_dir)))
         except FileNotFoundError:
             prog = None
         if prog is not None and abs(prog.window_s - ctx.trace.window_s) > 1e-6:

@@ -9,10 +9,12 @@ the traffic's length bounds, and runs each program once on dummy
 operands: that traces it, loads it from the persistent compile cache
 (or compiles it on a cold cache) and leaves it in the jit's own cache.
 
-Dummy steps write into the KV arenas, so both are zeroed afterwards;
-page inits write zeros over zeros and need nothing.
+Dummy steps write into the arenas (``bench.core.arenas``: the KV pair
+and, where the architecture carries recurrent state, the state arena),
+so all are zeroed afterwards; inits write zeros over zeros and need
+nothing.
 
-This drives the engine's jitted step callables and the RowClone page-op
+This drives the engine's jitted step callables and the RowClone init-op
 wrappers directly, with the operands the engine itself builds; it
 changes nothing in the engine.
 """
@@ -21,9 +23,10 @@ from __future__ import annotations
 
 from typing import Dict, List, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.core import arenas
 
 
 def pow2_upto(n: int) -> List[int]:
@@ -75,51 +78,36 @@ def _temps(b):
 
 
 def decode(eng, batch: int, width: int) -> None:
-    c = eng.cache
-    tok, k, v, _, _ = eng._step(
-        eng.params, _i32(batch, 1), c.k_arena, c.v_arena, _i32(batch, width),
+    (k, v), (conv, ssm, srows) = arenas.step_operands(eng.cache, batch)
+    tok, k, v, conv, ssm = eng._step(
+        eng.params, _i32(batch, 1), k, v, _i32(batch, width),
         jnp.asarray(np.ones((batch,), np.int32)), _i32(batch), _i32(batch),
-        _seed(eng), _temps(batch), None, None, None)
-    c.k_arena, c.v_arena = k, v
+        _seed(eng), _temps(batch), conv, ssm, srows)
+    arenas.adopt(eng.cache, k, v, conv, ssm)
     np.asarray(tok)
 
 
 def chunk(eng, sc: int, rows: int, width: int) -> None:
-    c = eng.cache
     n = rows * sc
-    tok, _, k, v, _, _ = eng._chunk_step(
+    (k, v), (conv, ssm, srows) = arenas.step_operands(eng.cache, rows)
+    tok, _, k, v, conv, ssm = eng._chunk_step(
         eng.params, _i32(rows, sc), jnp.asarray(np.ones((rows,), np.int32)),
-        _i32(rows), c.k_arena, c.v_arena, _i32(rows, width), _i32(rows),
-        _lst(n), _lst(n), _lst(n), _seed(eng), _temps(rows), None, None,
-        None, has_writes=True)
-    c.k_arena, c.v_arena = k, v
+        _i32(rows), k, v, _i32(rows, width), _i32(rows),
+        _lst(n), _lst(n), _lst(n), _seed(eng), _temps(rows), conv, ssm,
+        srows, has_writes=True)
+    arenas.adopt(eng.cache, k, v, conv, ssm)
     np.asarray(tok)
 
 
 def prefill(eng, sp: int, rows: int) -> None:
-    c = eng.cache
     n = rows * sp
-    tok, _, k, v, _, _ = eng._prefill_step(
+    (k, v), (conv, ssm, srows) = arenas.step_operands(eng.cache, rows)
+    tok, _, k, v, conv, ssm = eng._prefill_step(
         eng.params, _i32(rows, sp), jnp.asarray(np.ones((rows,), np.int32)),
-        c.k_arena, c.v_arena, _lst(n), _lst(n), _lst(n), _seed(eng),
-        _temps(rows), None, None, None, has_writes=True)
-    c.k_arena, c.v_arena = k, v
+        k, v, _lst(n), _lst(n), _lst(n), _seed(eng),
+        _temps(rows), conv, ssm, srows, has_writes=True)
+    arenas.adopt(eng.cache, k, v, conv, ssm)
     np.asarray(tok)
-
-
-def page_inits(eng, counts) -> None:
-    """One page-init program per page count (zeros written over the
-    zero pages at the top of the arena, which nothing holds yet)."""
-    from repro.kernels.rowclone import ops as rc_ops
-    c = eng.cache
-    top = c.k_arena.shape[1]
-    for n in counts:
-        dst = jnp.asarray(list(range(top - n, top)), jnp.int32)
-        c.k_arena = rc_ops.pim_page_init_batched(c.k_arena, dst, 0.0,
-                                                 use_pallas=eng.use_pallas)
-        c.v_arena = rc_ops.pim_page_init_batched(c.v_arena, dst, 0.0,
-                                                 use_pallas=eng.use_pallas)
-    jax.block_until_ready(c.k_arena)
 
 
 def plan(dep: Dict, mix: Dict) -> Dict[str, list]:
@@ -155,8 +143,12 @@ def run(eng, shapes: Dict[str, list]) -> int:
     for sp, r in shapes.get("prefill", []):
         prefill(eng, sp, r)
         n += 1
-    # zero both arenas whole (the dummy steps wrote into them), with the
-    # same page-init op the cache uses
-    page_inits(eng, [eng.cache.k_arena.shape[1]])
-    page_inits(eng, shapes.get("page_init", []))
-    return n + 1 + len(shapes.get("page_init", []))
+    # zero the arenas whole (the dummy steps wrote into them), with the
+    # same init ops the cache uses
+    n += arenas.zero(eng.cache, eng.use_pallas)
+    counts = shapes.get("page_init", [])
+    arenas.init_pages(eng.cache, counts, eng.use_pallas)
+    # the cache zeroes a freed sequence's state row in a flush of its own
+    # (PagedKVCache.free): one row at a time
+    arenas.init_state_rows(eng.cache, [1], eng.use_pallas)
+    return n + len(counts) + (eng.cache.state is not None)

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import math
 import zlib
-from typing import Dict, Sequence, Tuple
+from typing import Callable, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,9 +49,11 @@ def _key(words: jax.Array, leaf: str, layer) -> jax.Array:
 
 
 def leaf_std(path: str, shape: Sequence[int], d_model: int) -> float:
-    """Standard deviation of a weight by its role, from its path in the
-    parameter tree: norms are ones (std 0), token tables 0.02, and a
-    projection 1/sqrt(inputs summed into each output)."""
+    """The common rule for the leaves architectures share: standard
+    deviation of a weight by its role, from its path in the parameter
+    tree.  Norms are ones (std 0), token tables 0.02, and an attention or
+    MLP projection 1/sqrt(inputs summed into each output).  An
+    architecture module's own ``leaf_std`` may defer to it."""
     name = path.rsplit("/", 1)[-1]
     if name in ("norm", "final_norm") or path.endswith("norm"):
         return 0.0
@@ -105,14 +107,17 @@ def leaf_table(defs) -> Dict[str, Tuple[Tuple[int, ...], bool]]:
     return out
 
 
-def draw_tree(defs, seed: int, d_model: int, out_shardings=None):
-    """The whole parameter tree at bf16, in one jitted program; with
-    ``out_shardings`` (a tree like ``defs``) each chip draws its shard."""
+def draw_tree(defs, seed: int, d_model: int, std: Callable,
+              out_shardings=None):
+    """The whole parameter tree at bf16, in one jitted program, each leaf
+    at the standard deviation ``std(path, shape, d_model)`` gives (the
+    architecture module's ``leaf_std``); with ``out_shardings`` (a tree
+    like ``defs``) each chip draws its shard."""
     table = leaf_table(defs)
     treedef = jax.tree.structure(defs, is_leaf=lambda d: hasattr(d, "laxes"))
 
     def build(words):
-        leaves = [draw_leaf(words, p, s, leaf_std(p, s, d_model), st)
+        leaves = [draw_leaf(words, p, s, std(p, s, d_model), st)
                   for p, (s, st) in table.items()]
         return jax.tree.unflatten(treedef, leaves)
 

@@ -1,7 +1,11 @@
-"""KV the decode launches needed over KV they gathered, across the
-window, in percent: the real context tokens of the real rows
-(``engine.decode_kv_tokens``) over rows x table width x page size
-(``engine.decode_kv_slots``), both padded to powers of two."""
+"""KV the decode launches needed over KV they read, across the window,
+in percent: the arena tokens the real rows attend over
+(``engine.decode_kv_tokens``) over the token slots the launches read
+(``engine.decode_kv_slots``).  On a TPU the paged kernel reads only the
+pages below each row's length, so a slot count is each padded row's
+length rounded up to a whole page; on the jnp reference, which gathers
+the whole block table, it is rows x table width x page size.  Rows and
+table widths are padded to powers of two."""
 from bench.core.readers import counter_delta
 
 

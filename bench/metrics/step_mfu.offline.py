@@ -1,6 +1,6 @@
 """Whole-step utilisation: FLOPs the model required for the tokens made
-in the traced window (bench/core/flops.py) over device-busy seconds x
-chips x peak bf16 FLOP/s, in percent."""
+in the traced window (the architecture module's counts) over device-busy
+seconds x chips x peak bf16 FLOP/s, in percent."""
 from bench.core.readers import required_flops
 
 

@@ -1,5 +1,7 @@
-"""Plain float32 reference for a dense GQA decoder (granite-3-8b,
-minitron-8b as configured in ``bench/configs``).
+"""The dense GQA decoder's architecture module (bench/core/arch.py): the
+program's configuration for the file, the weights' initialisation, the
+plain float32 reference and the work each token requires, for granite-3-8b
+and minitron-8b as configured in ``bench/configs``.
 
 Pre-norm decoder layers: RMSNorm, grouped-query attention with
 GPT-NeoX half-rotation RoPE and a causal softmax scaled by
@@ -16,6 +18,12 @@ from the program itself.
 ``quant=True`` is the control: every matmul input (weights per
 tensor, activations per row) is rounded to float8_e4m3 with a scale,
 the precision one step below the bfloat16 the configuration states.
+
+The FLOP counts take only what the model needs: prompt tokens that the
+prefix cache did not serve, one decode step per generated token after
+the first, causal attention over the actual context, and one row of
+logits per sampled position.  Padding, duplicated pad rows and
+recomputation count as zero.
 """
 
 from __future__ import annotations
@@ -28,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.core import weights as W
+from bench.core.arch import Refused
 
 # the parameter paths of the served model's tree, and which are stacked
 # over layers (the benchmark draws the program's tree under these names)
@@ -35,6 +44,27 @@ ATTN = "group0/0_attn"
 MLP = "group0/1_mlp"
 PAD = 1024               # sequences are padded to a multiple of this
 ROWS = 512               # and the positions read to a multiple of this
+
+
+def model_config(cfg: Dict):
+    from repro.configs import ModelConfig
+    if cfg["model_type"] != "dense_gqa" or cfg["hidden_act"] != "silu":
+        raise Refused(f"{cfg['name']}: no engine path for "
+                      f"{cfg['model_type']}/{cfg['hidden_act']}")
+    return ModelConfig(
+        name=cfg["name"], family="dense",
+        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        activation="swiglu", norm_eps=cfg["rms_norm_eps"],
+        rope_theta=cfg["rope_theta"],
+        tie_embeddings=cfg["tie_word_embeddings"])
+
+
+# every leaf of this architecture (norms, token tables, attention, MLP)
+# takes the common rule
+leaf_std = W.leaf_std
 
 
 def _dims(cfg: Dict) -> Tuple[int, int, int, int, int, int]:
@@ -53,7 +83,7 @@ def layer_shapes(cfg: Dict) -> Dict[str, Tuple[int, ...]]:
 
 
 def _draw(words, path, shape, d, layer=None):
-    return W.draw_leaf(words, path, shape, W.leaf_std(path, shape, d),
+    return W.draw_leaf(words, path, shape, leaf_std(path, shape, d),
                        stacked=layer is not None, dtype=jnp.bfloat16,
                        layer=layer).astype(jnp.float32)
 
@@ -163,3 +193,52 @@ def logits_at(cfg: Dict, seed: int, seqs: Sequence[np.ndarray],
                 x, jnp.asarray(rows), words, g, eps=eps, quant=quant, d=d,
                 shape=(V, d), path=table))[:n])
         return out
+
+
+def layer_matmul_params(cfg: Dict) -> int:
+    d, h, kvh, hd, ff, _ = _dims(cfg)
+    return d * h * hd + 2 * d * kvh * hd + h * hd * d + 3 * d * ff
+
+
+def token_flops(cfg: Dict) -> float:
+    """Matmul FLOPs of one token through every layer (no attention
+    scores, no logits)."""
+    return 2.0 * cfg["num_hidden_layers"] * layer_matmul_params(cfg)
+
+
+def attention_flops(cfg: Dict, context: int) -> float:
+    """QK^T and PV of one query over ``context`` keys, every layer."""
+    return (4.0 * cfg["num_hidden_layers"] * cfg["num_attention_heads"]
+            * cfg["head_dim"] * context)
+
+
+def logits_flops(cfg: Dict) -> float:
+    return 2.0 * cfg["hidden_size"] * cfg["vocab_size"]
+
+
+def prefill_flops(cfg: Dict, prompt: int, cached: int) -> float:
+    """Prompt positions [cached, prompt) and the first token's logits."""
+    n = prompt - cached
+    # position p attends to p + 1 keys: sum over p in [cached, prompt)
+    keys = (prompt * (prompt + 1) - cached * (cached + 1)) // 2
+    return (n * token_flops(cfg) + attention_flops(cfg, 1) * keys
+            + logits_flops(cfg))
+
+
+def decode_flops(cfg: Dict, context: int) -> float:
+    """One generated token whose input sits at position context - 1."""
+    return (token_flops(cfg) + attention_flops(cfg, context)
+            + logits_flops(cfg))
+
+
+def page_bytes(cfg: Dict, page_size: int, kv_dtype_bytes: int = 2) -> int:
+    """Bytes of one KV page over all layers in ONE arena (K or V)."""
+    return (cfg["num_hidden_layers"] * page_size
+            * cfg["num_key_value_heads"] * cfg["head_dim"] * kv_dtype_bytes)
+
+
+def page_op_bytes(cfg: Dict, page_size: int, inits: int,
+                  copies: int) -> int:
+    """HBM bytes page ops must move over both arenas: an init writes a
+    page, a copy reads one and writes one."""
+    return 2 * page_bytes(cfg, page_size) * (inits + 2 * copies)

@@ -6,14 +6,15 @@
 
 Everything about a cell is data: ``BENCHMARK.json`` names its
 configuration (``bench/configs/<config>.json``) and traffic mix
-(``bench/traffic/<traffic>.json``), and each metric is read by
-``bench/metrics/<metric>.py``.  The run draws the weights on the chips
-from the seed, builds the engine the configuration's deployment
-describes, warms every program the traffic can reach, serves the
-traffic (a lead-in, then the measured window), then checks the served
-tokens against the plain float32 reference.  With ``--trace 1`` part of
-the window is profiled and the per-layer metrics are reported instead
-of the end-to-end ones.
+(``bench/traffic/<traffic>.json``), the configuration names its
+architecture module (``"reference"``, bench/core/arch.py), and each
+metric is read by ``bench/metrics/<metric>.py``.  The run draws the
+weights on the chips from the seed, builds the engine the
+configuration's deployment describes, warms every program the traffic
+can reach, serves the traffic (a lead-in, then the measured window),
+then checks the served tokens against the module's plain float32
+reference.  With ``--trace 1`` part of the window is profiled and the
+per-layer metrics are reported instead of the end-to-end ones.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
@@ -29,6 +30,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -41,10 +43,10 @@ import numpy as np  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
 
-
-class Refused(Exception):
-    """The run cannot measure here; exit non-zero, print no result."""
+from bench.core.arch import Refused  # noqa: E402
 
 
 def load_cell(name: str) -> dict:
@@ -66,29 +68,28 @@ def load_cell(name: str) -> dict:
             "per_layer": mine(spec["per_layer"])}
 
 
-def reader(metric: str):
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}",
-                                                  path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-def model_config(cfg: dict):
-    from repro.configs import ModelConfig
-    if cfg["model_type"] != "dense_gqa" or cfg["hidden_act"] != "silu":
-        raise Refused(f"{cfg['name']}: no engine path for "
-                      f"{cfg['model_type']}/{cfg['hidden_act']}")
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        activation="swiglu", norm_eps=cfg["rms_norm_eps"],
-        rope_theta=cfg["rope_theta"],
-        tie_embeddings=cfg["tie_word_embeddings"])
+def reader(metric: str):
+    return _load(BENCH / "metrics" / f"{metric}.py",
+                 f"bench_metric_{metric}").read
+
+
+def architecture(cfg: dict):
+    """The configuration's architecture module: the file it names under
+    ``"reference"``, loaded by path (once a process, so its jitted
+    functions keep their compiled programs)."""
+    return _architecture(ROOT / cfg["reference"])
+
+
+@functools.lru_cache(maxsize=None)
+def _architecture(path: Path):
+    return _load(path, f"bench_arch_{path.stem}")
 
 
 class CompileClock:
@@ -108,7 +109,7 @@ class CompileClock:
         jax.monitoring.register_event_duration_secs_listener(listen)
 
 
-def build(cfg: dict, dep: dict, seed: int):
+def build(arch, cfg: dict, dep: dict, seed: int):
     """Weights drawn on the chips (each chip its own shard), and the
     engine the deployment describes."""
     import jax
@@ -119,9 +120,9 @@ def build(cfg: dict, dep: dict, seed: int):
     from repro.models import transformer as T
     from repro.serving.engine import PagedEngine
 
-    from bench.core import weights
+    from bench.core import arenas, weights
 
-    mcfg = model_config(cfg)
+    mcfg = arch.model_config(cfg)
     defs = T.model_defs(mcfg)
     mesh = None
     if dep["mesh_model"] > 1:
@@ -135,7 +136,7 @@ def build(cfg: dict, dep: dict, seed: int):
 
     def draw(seed):
         return weights.draw_tree(defs, seed, cfg["hidden_size"],
-                                 out_shardings=shard)
+                                 arch.leaf_std, out_shardings=shard)
 
     params = draw(seed)
     eng = PagedEngine(mcfg, params, page_size=dep["page_size"],
@@ -143,7 +144,7 @@ def build(cfg: dict, dep: dict, seed: int):
                       prefix_cache=dep["prefix_cache"],
                       max_prefill_chunk=dep["max_prefill_chunk"],
                       mixed_rounds=dep["mixed_rounds"], mesh=mesh)
-    jax.block_until_ready((eng.params, eng.cache.k_arena))
+    jax.block_until_ready((eng.params, arenas.arrays(eng.cache)))
     return eng, draw
 
 
@@ -189,35 +190,25 @@ def _serve(eng, c: dict, seed: int, seconds: float, tracer, clock):
                              clock)
 
 
-def check_seeds(eng, draw, c: dict, args, clock, log) -> int:
+def check_seeds(eng, draw, arch, c: dict, args, clock, log) -> int:
     """For each seed: new weights, the cell's traffic for a short window
     on the same engine, then the served tokens (and with ``--control``
-    the float8 control) against the reference, with the engine's
+    the module's control) against the reference, with the engine's
     weights and arenas freed meanwhile."""
-    import jax
-    import jax.numpy as jnp
-    from bench.core import check, drive
+    from bench.core import arenas, check, drive
     cfg, ck = c["cfg"], c["cfg"]["check"]
-    arena = eng.cache.k_arena
-    shape, dtype, sharding = arena.shape, arena.dtype, arena.sharding
-
-    def zeros():
-        # as the cache builds its arenas: zeros, then placed on the mesh
-        z = jnp.zeros(shape, dtype)
-        return z if eng.mesh is None else jax.device_put(z, sharding)
-
+    shapes = arenas.layout(eng.cache)
     for i, seed in enumerate(int(x) for x in args.seeds.split(",")):
         if i:
             eng.params = draw(seed)
-            eng.cache.k_arena = zeros()
-            eng.cache.v_arena = zeros()
+            arenas.rebuild(eng.cache, shapes)
         records, win = _serve(eng, c, seed, args.seconds,
                               drive.Tracer(None, 0, eng), clock)
         eng.run(max_rounds=1 << 30)
         if eng.cache.prefix is not None:
             eng.cache.clear_prefix()
         eng.params = None
-        eng.cache.k_arena = eng.cache.v_arena = None
+        arenas.free(eng.cache)
         gc.collect()
         picked = check.sample(records, seed, ck["min_tokens"],
                               ck["max_seqs"])
@@ -227,9 +218,10 @@ def check_seeds(eng, draw, c: dict, args, clock, log) -> int:
                "sampled": [[len(r.prompt), r.cached, len(r.tokens)]
                            for r in picked]}
         if picked:
-            out["program"] = check.served_gap(cfg, seed, picked)
+            out["program"] = check.served_gap(arch, cfg, seed, picked)
             if args.control:
-                out["control"] = check.control_gaps(cfg, seed, picked)
+                out["control"] = check.control_gaps(arch, cfg, seed,
+                                                    picked)
         log(**out)
     return 0
 
@@ -281,9 +273,10 @@ def parser() -> argparse.ArgumentParser:
                          "of each on one engine (setting the limits; no "
                          "result line)")
     ap.add_argument("--control", action="store_true",
-                    help="score the float8 control in the program's "
-                         "place (it must read correct: false); with "
-                         "--seeds, read it beside the program")
+                    help="score the architecture module's control (the "
+                         "reference one precision step lower) in the "
+                         "program's place (it must read correct: false); "
+                         "with --seeds, read it beside the program")
     return ap
 
 
@@ -293,7 +286,7 @@ def main(argv=None) -> int:
         print("bench: the program (src/repro) is not in this checkout; "
               "nothing run", file=sys.stderr)
         return 2
-    sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
+    sys.path.insert(0, str(ROOT / "src"))
     try:
         return run(args, load_cell(args.workload))
     except Refused as e:
@@ -316,7 +309,7 @@ def run(args, c: dict, require_tpu: bool = True) -> int:
                       f"{len(devs)}")
     from repro.launch.compile_cache import enable_compile_cache
 
-    from bench.core import check, drive, peaks, trace, warmup
+    from bench.core import arenas, check, drive, peaks, trace, warmup
 
     if require_tpu:
         enable_compile_cache()
@@ -333,7 +326,8 @@ def run(args, c: dict, require_tpu: bool = True) -> int:
         print(json.dumps(kw), file=sys.stderr, flush=True)
 
     t = time.perf_counter()
-    eng, draw = build(cfg, dep, args.seed)
+    arch = architecture(cfg)
+    eng, draw = build(arch, cfg, dep, args.seed)
     log(phase="build", seconds=time.perf_counter() - t,
         compile_s=clock.seconds, programs=clock.count)
     t, c0, n0 = time.perf_counter(), clock.seconds, clock.count
@@ -347,7 +341,7 @@ def run(args, c: dict, require_tpu: bool = True) -> int:
     if args.sweep:
         return sweep(eng, c, args, clock, log)
     if args.seeds:
-        return check_seeds(eng, draw, c, args, clock, log)
+        return check_seeds(eng, draw, arch, c, args, clock, log)
     log_dir = None
     if args.trace:
         log_dir = str(c.get("out", ROOT / "bench_out") / "trace")
@@ -382,7 +376,7 @@ def run(args, c: dict, require_tpu: bool = True) -> int:
             f"plan, so the window measured compiles")
     # free the program's weights and arenas before the reference runs
     eng.params = None
-    eng.cache.k_arena = eng.cache.v_arena = None
+    arenas.free(eng.cache)
     del eng, tracer.eng
     gc.collect()
 
@@ -391,10 +385,10 @@ def run(args, c: dict, require_tpu: bool = True) -> int:
     ck = cfg["check"]
     picked = check.sample(records, args.seed, ck["min_tokens"],
                           ck["max_seqs"])
-    # with --control the float8 reference's tokens stand in for the
+    # with --control the tokens of the module's control stand in for the
     # served ones, through the same comparison and limit
     score = check.control_gaps if args.control else check.served_gap
-    gap = score(cfg, args.seed, picked) if picked else None
+    gap = score(arch, cfg, args.seed, picked) if picked else None
     compared = {}
     if gap is not None:
         compared["max_logit_gap"] = {"value": gap["max_logit_gap"],
@@ -412,9 +406,10 @@ def run(args, c: dict, require_tpu: bool = True) -> int:
     summary = None
     if args.trace:
         summary = trace.summarise(trace.load(log_dir))
-    ctx = SimpleNamespace(cfg=cfg, dep=dep, mix=mix, chips=chips, peak=peak,
-                          setup_s=setup_s, seconds=secs, records=records,
-                          win=win, trace=summary)
+    ctx = SimpleNamespace(cfg=cfg, arch=arch, dep=dep, mix=mix, chips=chips,
+                          peak=peak, setup_s=setup_s, seconds=secs,
+                          records=records, win=win, trace=summary,
+                          trace_dir=log_dir)
     metrics = {}
     for m in (c["per_layer"] if args.trace else c["end_to_end"]):
         v = reader(m["name"])(ctx)

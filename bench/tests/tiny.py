@@ -7,6 +7,7 @@ import copy
 
 CFG = {
     "name": "tiny-gqa", "model_type": "dense_gqa",
+    "reference": "bench/reference/dense_gqa.py",
     "num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
     "vocab_size": 512, "hidden_act": "silu", "tie_word_embeddings": True,
