@@ -38,6 +38,15 @@ def layout(cache) -> list:
     return [(x.shape, x.dtype, x.sharding) for x in arrays(cache)]
 
 
+def describe(cache) -> list:
+    """Name, shape, dtype and bytes of each array, for the log: the
+    cache's share of a cell's memory."""
+    return [{"name": a if o is cache else f"state.{a}",
+             "shape": list(x.shape), "dtype": str(x.dtype),
+             "bytes": int(x.nbytes)}
+            for (o, a), x in zip(_named(cache), arrays(cache))]
+
+
 def rebuild(cache, shapes: list) -> None:
     """Every array as zeros of its ``layout``, as the cache builds them:
     zeros, then placed on the mesh where the cache has one."""

@@ -328,8 +328,12 @@ def run(args, c: dict, require_tpu: bool = True) -> int:
     t = time.perf_counter()
     arch = architecture(cfg)
     eng, draw = build(arch, cfg, dep, args.seed)
+    held = arenas.describe(eng.cache)
     log(phase="build", seconds=time.perf_counter() - t,
-        compile_s=clock.seconds, programs=clock.count)
+        compile_s=clock.seconds, programs=clock.count,
+        state_slots=(eng.cache.state.num_slots
+                     if eng.cache.state is not None else None),
+        arena_bytes=sum(a["bytes"] for a in held), arenas=held)
     t, c0, n0 = time.perf_counter(), clock.seconds, clock.count
     shapes = warmup.plan(dep, mix)
     n_prog = warmup.run(eng, shapes)

@@ -135,6 +135,17 @@ def test_new_architecture_from_files_alone(tmp_path, monkeypatch, capsys):
     assert res["correct"] is True, err[-3000:]
     for m in mine[1:]:
         assert res["metrics"][m]["value"] > 0, res["metrics"]
+    # the build's log gives the cache's arrays and the state row count:
+    # one row per KV page, as the engine sizes its state arena today
+    build = next(json.loads(ln) for ln in err.splitlines()
+                 if ln.startswith('{"phase": "build"'))
+    pages = HYBRID["deployment"]["num_pages"]
+    assert build["state_slots"] == pages
+    held = {a["name"]: a for a in build["arenas"]}
+    assert set(held) == {"k_arena", "v_arena", "state.conv", "state.ssm"}
+    assert held["state.ssm"]["shape"][2] == pages
+    assert held["state.ssm"]["dtype"] == "float32"
+    assert build["arena_bytes"] == sum(a["bytes"] for a in held.values())
     arch = R.architecture(c["cfg"])
     assert arch.__file__ == str(tmp_path / "bench/reference/tiny_hybrid.py")
     with pytest.raises(R.Refused, match="no control"):

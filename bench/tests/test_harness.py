@@ -32,6 +32,11 @@ def test_chat_cell_is_correct_and_the_control_is_not(capsys):
     assert res["device"]["platform"] == "cpu"
     assert list(res)[-1] == "compared"
     assert err.strip().splitlines()[-1].startswith("compared ")
+    build = next(json.loads(ln) for ln in err.splitlines()
+                 if ln.startswith('{"phase": "build"'))
+    assert build["state_slots"] is None
+    assert [a["name"] for a in build["arenas"]] == ["k_arena", "v_arena"]
+    assert build["arena_bytes"] == sum(a["bytes"] for a in build["arenas"])
     # the float8 control, scored in the program's place, must fail
     ctl, _ = _run(capsys, tiny.cell(), seed=seed, control=True)
     assert ctl["correct"] is False, ctl
